@@ -1,15 +1,16 @@
-"""Mesh-sharded DSE scaling: stage-2/stage-4 cand/s over 1/2/4/8 devices.
+"""Mesh-sharded DSE scaling: stage-2/stage-4 cand/s over 1, 2, 4, ... devices.
 
 Runs the batched surrogate (stage 2) and finite-buffer verifier (stage 4)
-over a 256-candidate batch at ``MeshSpec(devices=d)`` for d in 1/2/4/8
-*simulated host devices* (a subprocess forces them with
-``--xla_force_host_platform_device_count=8``; the parent process keeps its
-real device topology).  Because simulated devices share the host's physical
-cores, the honest ideal aggregate throughput of an N-device mesh is
-``serial * min(N, host_cores)`` — per-device efficiency is measured against
-that, not against an N× fantasy the silicon can't deliver.  The bar is
->= 0.7x per-device efficiency at 8 devices: sharding dispatch overhead may
-cost at most 30% of the throughput the host can physically provide.
+over a 256-candidate batch at ``MeshSpec(devices=d)`` for every power of two
+d up to ``jax.device_count()``, in the calling process — the process that
+holds the devices (chips, or simulated host devices forced with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  With fewer than two
+devices there is no curve to measure, and the suite fails.  Simulated
+devices share the host's physical cores, so the ideal aggregate throughput
+of an N-device mesh is ``serial * min(N, host_cores)`` — per-device
+efficiency is measured against that.  The bar is >= 0.7x per-device
+efficiency at the widest mesh: sharding dispatch overhead may cost at most
+30% of the throughput the hardware can provide.
 
 Correctness is asserted, not sampled: every device count must produce
 bitwise-identical stage-2/stage-4 arrays and an identical NSGA-II Pareto
@@ -17,26 +18,34 @@ front (the determinism contract from ``tests/test_mesh_dse.py``), so a
 scaling number from a silently-diverged shard can never land in
 ``BENCH_dse.json``.
 
-    python -m benchmarks.mesh_scaling
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python -m benchmarks.mesh_scaling
 """
 
-import json
 import os
-import subprocess
-import sys
+import time
 
 from .common import emit
 
-DEVICE_COUNTS = (1, 2, 4, 8)
 BATCH = 256
 EFFICIENCY_BAR = 0.7
-_WORKER_FLAG = "--worker"
 
 
-def _worker() -> None:
-    """Measure inside the forced-8-device subprocess; print one JSON line."""
-    import time
+def device_counts(available: int):
+    """1, 2, 4, ... up to ``available``; raises below two devices."""
+    if available < 2:
+        raise RuntimeError(
+            f"mesh_scaling needs at least 2 devices, found {available} (on "
+            "the CPU, force host devices with XLA_FLAGS="
+            "--xla_force_host_platform_device_count=8)")
+    counts = [1]
+    while counts[-1] * 2 <= available:
+        counts.append(counts[-1] * 2)
+    return tuple(counts)
 
+
+def measure() -> dict:
+    """Measure every device count in this process; returns the result."""
     import jax
     import numpy as np
 
@@ -50,11 +59,7 @@ def _worker() -> None:
     from repro.sim.switch_problem import align_depth_to_bram
     from repro.traces import hft
 
-    if jax.device_count() < max(DEVICE_COUNTS):
-        print(json.dumps({"skipped": f"backend exposes {jax.device_count()} "
-                          f"devices, cannot force {max(DEVICE_COUNTS)}"}))
-        return
-
+    counts = device_counts(jax.device_count())
     bound = bind(compressed_protocol(addr_bits=4, length_bits=6),
                  flit_bits=256)
     tr = hft(seed=0)
@@ -85,7 +90,7 @@ def _worker() -> None:
 
     stage2, stage4 = {}, {}
     bitwise = pareto = True
-    for d in DEVICE_COUNTS:
+    for d in counts:
         mesh = None if d == 1 else MeshSpec(devices=d)
         f2 = lambda: run_surrogate_batched(cands, bound, tr,
                                            back_annotation=False, mesh=mesh)
@@ -108,40 +113,29 @@ def _worker() -> None:
         pareto &= front == ref_front
 
     cores = os.cpu_count() or 1
-    n_max = DEVICE_COUNTS[-1]
+    n_max = counts[-1]
     ideal = min(n_max, cores)            # simulated devices share host cores
     eff2 = (stage2[n_max] / stage2[1]) / ideal
     eff4 = (stage4[n_max] / stage4[1]) / ideal
-    print(json.dumps({
-        "device_counts": list(DEVICE_COUNTS), "batch": BATCH,
-        "host_cores": cores, "ideal_speedup_at_8": ideal,
-        "stage2_cands_per_sec": {str(d): stage2[d] for d in DEVICE_COUNTS},
-        "stage4_cands_per_sec": {str(d): stage4[d] for d in DEVICE_COUNTS},
-        "stage2_efficiency_at_8": eff2, "stage4_efficiency_at_8": eff4,
+    return {
+        "device": {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind,
+                   "count": jax.device_count()},
+        "device_counts": list(counts), "batch": BATCH,
+        "host_cores": cores, "ideal_speedup": ideal,
+        "stage2_cands_per_sec": {str(d): stage2[d] for d in counts},
+        "stage4_cands_per_sec": {str(d): stage4[d] for d in counts},
+        "stage2_efficiency": eff2, "stage4_efficiency": eff4,
         "efficiency_bar": EFFICIENCY_BAR,
         "stage2_pass": eff2 >= EFFICIENCY_BAR,
         "stage4_pass": eff4 >= EFFICIENCY_BAR,
         "bitwise_identical": bitwise, "pareto_identical": pareto,
-    }))
+    }
 
 
 def run():
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                        f"{max(DEVICE_COUNTS)}")
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(repo, "src"), env.get("PYTHONPATH")) if p)
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.mesh_scaling", _WORKER_FLAG],
-        env=env, cwd=repo, capture_output=True, text=True, timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(f"mesh_scaling worker failed:\n{out.stderr[-4000:]}")
-    res = json.loads(out.stdout.strip().splitlines()[-1])
-    if "skipped" in res:
-        emit("mesh_scaling/skipped", 0.0, res["skipped"])
-        return res
-
+    res = measure()
+    dev = res["device"]
     for d in res["device_counts"]:
         c2 = res["stage2_cands_per_sec"][str(d)]
         c4 = res["stage4_cands_per_sec"][str(d)]
@@ -149,13 +143,13 @@ def run():
              f"{c2:.0f} cand/s over B={res['batch']}")
         emit(f"mesh_scaling/stage4_devices_{d}", 1e6 / c4,
              f"{c4:.0f} cand/s verify")
-    ideal = res["ideal_speedup_at_8"]
-    note = (f"ideal={ideal}x on {res['host_cores']} host core(s); "
-            f"simulated devices share cores")
+    n_max = res["device_counts"][-1]
+    note = (f"ideal={res['ideal_speedup']}x on {res['host_cores']} host "
+            f"core(s); {dev['count']}x {dev['platform']} {dev['kind']}")
     for stage in ("stage2", "stage4"):
-        eff = res[f"{stage}_efficiency_at_8"]
+        eff = res[f"{stage}_efficiency"]
         verdict = "PASS" if res[f"{stage}_pass"] else "FAIL"
-        emit(f"mesh_scaling/{stage}_efficiency_at_8", 0.0,
+        emit(f"mesh_scaling/{stage}_efficiency_at_{n_max}", 0.0,
              f"{eff:.2f}x per-device ({verdict} >={EFFICIENCY_BAR}x bar; {note})")
     emit("mesh_scaling/bitwise_identical", 0.0, str(res["bitwise_identical"]))
     emit("mesh_scaling/pareto_identical", 0.0, str(res["pareto_identical"]))
@@ -167,7 +161,4 @@ def run():
 
 
 if __name__ == "__main__":
-    if _WORKER_FLAG in sys.argv:
-        _worker()
-    else:
-        run()
+    run()

@@ -11,12 +11,12 @@ from .common import emit, timed
 
 
 def run():
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.config import ModelConfig, ShardingPlan
     from repro.models.moe import MoEOptions, apply_moe, init_moe
     from repro.kernels.quant_pack.ops import compression_ratio
 
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = ModelConfig(name="bench", family="moe", n_layers=1, d_model=512,
                       n_heads=8, n_kv_heads=4, d_ff=1024, vocab=1000,
                       moe_experts=16, moe_topk=2)

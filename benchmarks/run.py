@@ -38,8 +38,8 @@ SUITES = {
     "moe_fabric": moe_fabric.run,
     "dse_throughput": dse_throughput.run,
     "search": search_quality.run,
-    # device-mesh sharding: stage-2/stage-4 cand/s over 1/2/4/8 simulated
-    # host devices + bitwise/Pareto identity asserts (subprocess, 8 forced)
+    # device-mesh sharding: stage-2/stage-4 cand/s over 1, 2, 4, ... of this
+    # process's devices + bitwise/Pareto identity asserts (fails below 2)
     "mesh_scaling": mesh_scaling.run,
     # segmented netsim kernels vs the oracle engines on a 256-candidate
     # sized hft sweep — >=5x stage-4 bar + bitwise parity, both hard-fail

@@ -9,7 +9,7 @@ import time
 
 import jax
 
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 import numpy as np
 
 from repro.configs import get_smoke
@@ -26,7 +26,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args()
 
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_smoke(args.arch)
     plan = SINGLE_POD_PLAN
     params, _ = T.init_params(jax.random.PRNGKey(0), cfg, plan)

@@ -15,7 +15,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 
 from repro.data import DataConfig, SyntheticLM
 from repro.models import SINGLE_POD_PLAN, ModelConfig
@@ -40,7 +40,7 @@ def main():
                     help="step at which to kill the 'node' (default steps//2)")
     args = ap.parse_args()
 
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = model_100m()
     plan = SINGLE_POD_PLAN
     print(f"model: {cfg.name} — {cfg.param_count()/1e6:.0f}M params")

@@ -78,7 +78,7 @@ RULES: Dict[str, Rule] = {r.code: r for r in (
          "the PR 6 sharded engines were rebuilt around lru-cached pure "
          "builders precisely to avoid this class"),
     Rule("SPAC206", "unscoped enable_x64 / global jax_enable_x64 flip",
-         "x64 is scoped per engine call (`with enable_x64():`, PR 4); a "
+         "x64 is scoped per engine call (`with jax.enable_x64():`); a "
          "process-wide flip changes every other engine's dtypes mid-run",
          "surrogate quantile math needs f64 while netsim runs f32 — one "
          "global `config.update` would corrupt whichever runs second"),
@@ -418,7 +418,7 @@ def _check_x64(tree: ast.AST) -> List[_Finding]:
                 "SPAC206", node.lineno,
                 "enable_x64() called outside a with-block leaks x64 into "
                 "every engine that runs afterwards",
-                hint="scope it: `with enable_x64(): ...`"))
+                hint="scope it: `with jax.enable_x64(): ...`"))
         elif name.split(".")[-1] == "update" and node.args \
                 and isinstance(node.args[0], ast.Constant) \
                 and node.args[0].value == "jax_enable_x64":
@@ -426,8 +426,7 @@ def _check_x64(tree: ast.AST) -> List[_Finding]:
                 "SPAC206", node.lineno,
                 "global jax_enable_x64 flip changes dtypes for the whole "
                 "process",
-                hint="use the scoped `with enable_x64():` helper from "
-                     "repro.sim instead"))
+                hint="scope it per engine call: `with jax.enable_x64(): ...`"))
     return out
 
 

@@ -47,14 +47,15 @@ def tracked_names():
 
 
 def _jit_cache_size(fn: Any) -> int:
-    """Distinct traced entries of one jitted callable (0 if unreadable)."""
+    """Distinct traced entries of one jitted callable.
+
+    Raises ``TypeError`` for a callable without a readable jit cache: a
+    silent 0 would let every zero-compilations guard pass unseen."""
     probe = getattr(fn, "_cache_size", None)
-    if callable(probe):
-        try:
-            return int(probe())
-        except Exception:       # pragma: no cover - jax-internal API drift
-            return 0
-    return 0
+    if not callable(probe):
+        raise TypeError(f"{fn!r} exposes no jit cache size; track() only "
+                        "jax.jit-wrapped callables")
+    return int(probe())
 
 
 def compile_counts() -> Dict[str, int]:

@@ -630,6 +630,10 @@ def _cmd_serve(args) -> int:
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    if args.cmd in ("run", "sweep", "serve"):
+        # the commands that compile; the others stay free of a jax import
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     return {"list": _cmd_list, "show": _cmd_show, "check": _cmd_check,
             "lint": _cmd_lint, "ingest": _cmd_ingest, "run": _cmd_run,
             "sweep": _cmd_sweep, "serve": _cmd_serve}[args.cmd](args)

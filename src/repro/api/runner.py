@@ -87,12 +87,12 @@ def _build_comm_problem(scenario: Scenario) -> DSEProblem:
     import jax.numpy as jnp
 
     from repro.comm.dse_comm import CommDSEProblem
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import SINGLE_POD_PLAN, ModelConfig
     from repro.models.moe import init_moe
 
     c = scenario.comm
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = ModelConfig(name=scenario.name, family="moe", n_layers=1,
                       d_model=c.d_model, n_heads=c.n_heads,
                       n_kv_heads=c.n_kv_heads, d_ff=c.d_ff, vocab=c.vocab,

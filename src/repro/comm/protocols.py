@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.kernels.quant_pack import ref as qref
 
 __all__ = ["compressed_mean", "wrap_grad_fn_with_pod_protocol"]
@@ -49,14 +48,6 @@ def compressed_mean(grads, axis: str):
 def wrap_grad_fn_with_pod_protocol(grad_fn: Callable, mesh, *, payload: str = "int8"):
     """grad_fn(params, batch) -> ((loss, metrics), grads), pod-synchronised
     with the chosen payload protocol."""
-    if not compat.has_partial_manual_shard_map():
-        # fail fast: on 0.4.x the partial-manual all_gather below aborts the
-        # whole process with a native XLA CHECK, which is uncatchable
-        raise NotImplementedError(
-            "the pod gradient protocol needs partial-manual shard_map "
-            "(jax >= 0.5); this JAX's SPMD partitioner CHECK-fails on "
-            "manual-subgroup collectives")
-
     def wrapped(params, batch):
         def inner(p, b):
             (loss, metrics), g = grad_fn(p, b)          # pod-local gradients
@@ -68,11 +59,11 @@ def wrap_grad_fn_with_pod_protocol(grad_fn: Callable, mesh, *, payload: str = "i
             metrics = jax.tree.map(lambda m: jax.lax.pmean(m, "pod"), metrics)
             return (loss, metrics), g
 
-        return compat.shard_map(
-            inner, mesh, axis_names={"pod"},
+        return jax.shard_map(
+            inner, mesh=mesh, axis_names={"pod"},
             in_specs=(P(), P("pod")),
             out_specs=((P(), P()), P()),
-            check=False,
+            check_vma=False,
         )(params, batch)
 
     return wrapped

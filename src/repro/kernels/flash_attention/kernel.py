@@ -68,7 +68,7 @@ def flash_attention_bhsd(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     bh, s, d = q.shape
     t = k.shape[1]

@@ -16,7 +16,7 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     b, hq, s, d = q.shape
     hkv = k.shape[1]

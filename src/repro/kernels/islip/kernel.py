@@ -78,7 +78,7 @@ def islip_schedule_padded(
     iters: int = 2,
     n_valid: int = 16,
     block_b: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     b, np_, _ = req.shape
     assert b % block_b == 0, (b, block_b)

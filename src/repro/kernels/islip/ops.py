@@ -11,7 +11,7 @@ LANES = 128
 
 
 def islip_schedule(req, gptr, aptr, *, iters: int = 2, use_pallas: bool = True,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """req [B, N, N] -> (match, gptr', aptr').  N padded to 128 internally."""
     b, n, _ = req.shape
     if not use_pallas:

@@ -1,21 +1,32 @@
 """Admission-gated lean replay as a Pallas kernel, candidate-tiled.
 
-Like the crossbar kernel, one replay step is tiny (two ``[B, N]`` slack
-vectors), so the grid runs over *candidate blocks*: each program keeps the
-port-slack state for ``block_b`` candidates resident in VMEM scratch and
-walks the shared event timeline with a ``fori_loop``, processing one event
-per iteration lane-parallel across the batch.  Port gather/scatter is done
-by masking against a lane iota (ports are padded to the 128-lane boundary
-by ``ops.lean_replay``).  B×E work therefore maps to ``B/block_b`` grid
-blocks instead of B lanes inside one host scan.
+Like the crossbar kernel, one replay step is tiny (two ``[N, B]`` slack
+arrays), so the grid runs over *candidate blocks* × *event blocks*: each
+program keeps the port-slack state for ``block_b`` candidates resident in
+VMEM scratch and walks its ``block_m`` events of the shared timeline with a
+``fori_loop``, processing one event per iteration lane-parallel across the
+candidates.  The event axis is the grid's sequential ("arbitrary") axis, so
+the scratch carries port state from one event block to the next.
 
-Contract (per batch row): dnow [1, m] float32, src/dst [1, m] int32 shared;
-svc [B, m] float32, admit [B, m] float32 (1.0 admitted / 0.0 dropped),
-pipe [B, 1] float32 per candidate → dep [B, m] float32 departure *offsets*.
-Implements the slack formulation (``ref.netsim_replay_slack_ref``) — the
-carries never hold absolute timestamps, so float32 survives long traces —
-and matches that oracle bit-for-bit in interpret mode
-(``tests/test_netsim_kernels.py``).
+Layout (what Mosaic needs on a TPU):
+
+* the shared timeline scalars (``dnow``/``src``/``dst``) are 1-D SMEM blocks,
+  read as scalars per event;
+* per-event candidate data is *event-major* — ``svc``/``admit``/``dep`` are
+  ``[m, B]`` with candidates on the 128-wide lane axis — so the per-event
+  read and write is a dynamic *sublane* row ``[1, block_b]``, never a
+  dynamic lane offset;
+* ports sit on the sublane axis of the ``[n_pad, block_b]`` state (padded to
+  a multiple of 8 by ``ops.lean_replay``); gather/scatter is a mask against a
+  sublane iota.
+
+Contract: dnow [m] float32, src/dst [m] int32 shared; svc [m, B] float32,
+admit [m, B] float32 (1.0 admitted / 0.0 dropped), pipe [1, B] float32 per
+candidate → dep [m, B] float32 departure *offsets*; ``m`` a multiple of
+``block_m`` and ``B`` of ``block_b``.  Implements the slack formulation
+(``ref.netsim_replay_slack_ref``) — the carries never hold absolute
+timestamps, so float32 survives long traces — and matches that oracle
+bit-for-bit in interpret mode (``tests/test_netsim_kernels.py``).
 """
 
 from __future__ import annotations
@@ -29,67 +40,70 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _netsim_kernel(dnow_ref, src_ref, dst_ref, svc_ref, admit_ref, pipe_ref,
-                   dep_ref, in_s, out_s, *, m: int):
-    in_s[...] = jnp.zeros_like(in_s)
-    out_s[...] = jnp.zeros_like(out_s)
-    lane = jax.lax.broadcasted_iota(jnp.int32, in_s.shape, 1)   # [B, Np]
-    pipe = pipe_ref[...][:, 0]                                  # [B]
+                   dep_ref, in_s, out_s, *, block_m: int):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        in_s[...] = jnp.zeros_like(in_s)
+        out_s[...] = jnp.zeros_like(out_s)
+
+    port = jax.lax.broadcasted_iota(jnp.int32, in_s.shape, 0)   # [Np, B]
+    pipe = pipe_ref[...]                                        # [1, B]
 
     def body(k, _):
-        dtk = dnow_ref[0, k]
-        i = src_ref[0, k]
-        j = dst_ref[0, k]
-        s = pl.load(svc_ref, (slice(None), pl.ds(k, 1)))[:, 0]      # [B]
-        ad = pl.load(admit_ref, (slice(None), pl.ds(k, 1)))[:, 0] > 0.5
+        dtk = dnow_ref[k]
+        i = src_ref[k]
+        j = dst_ref[k]
+        s = svc_ref[pl.ds(k, 1), :]                             # [1, B]
+        ad = admit_ref[pl.ds(k, 1), :] > 0.5
         ins = jnp.maximum(in_s[...] - dtk, 0.0)
         outs = jnp.maximum(out_s[...] - dtk, 0.0)
         wait = jnp.maximum(
             jnp.maximum(
-                jnp.max(jnp.where(lane == i, ins, 0.0), axis=1),
-                jnp.max(jnp.where(lane == j, outs, 0.0), axis=1)),
+                jnp.max(jnp.where(port == i, ins, 0.0), axis=0, keepdims=True),
+                jnp.max(jnp.where(port == j, outs, 0.0), axis=0,
+                        keepdims=True)),
             pipe)
-        dep = wait + s                                              # [B]
-        upd = ad[:, None]
-        in_s[...] = jnp.where((lane == i) & upd, dep[:, None], ins)
-        out_s[...] = jnp.where((lane == j) & upd, dep[:, None], outs)
-        pl.store(dep_ref, (slice(None), pl.ds(k, 1)), dep[:, None])
+        dep = wait + s                                          # [1, B]
+        in_s[...] = jnp.where((port == i) & ad, dep, ins)
+        out_s[...] = jnp.where((port == j) & ad, dep, outs)
+        dep_ref[pl.ds(k, 1), :] = dep
         return 0
 
-    jax.lax.fori_loop(0, m, body, 0)
+    jax.lax.fori_loop(0, block_m, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("n_pad", "block_b", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_pad", "block_b", "block_m",
+                                             "interpret"))
 def netsim_replay_padded(
-    dnow: jnp.ndarray,   # [1, m] float32
-    src: jnp.ndarray,    # [1, m] int32
-    dst: jnp.ndarray,    # [1, m] int32
-    svc: jnp.ndarray,    # [B, m] float32 (B a multiple of block_b)
-    admit: jnp.ndarray,  # [B, m] float32 (1.0 / 0.0)
-    pipe: jnp.ndarray,   # [B, 1] float32
+    dnow: jnp.ndarray,   # [m] float32
+    src: jnp.ndarray,    # [m] int32
+    dst: jnp.ndarray,    # [m] int32
+    svc: jnp.ndarray,    # [m, B] float32 (event-major)
+    admit: jnp.ndarray,  # [m, B] float32 (1.0 / 0.0)
+    pipe: jnp.ndarray,   # [1, B] float32
     *,
-    n_pad: int,          # ports padded to the lane boundary
-    block_b: int = 8,
-    interpret: bool = True,
+    n_pad: int,          # ports padded to the sublane boundary
+    block_b: int = 128,
+    block_m: int = 1024,
+    interpret: bool = False,
 ):
-    b, m = svc.shape
-    assert b % block_b == 0, (b, block_b)
-    kern = functools.partial(_netsim_kernel, m=m)
+    m, b = svc.shape
+    assert b % block_b == 0 and m % block_m == 0, (m, b, block_m, block_b)
+    scalars = pl.BlockSpec((block_m,), lambda c, e: (e,),
+                           memory_space=pltpu.SMEM)
+    events = pl.BlockSpec((block_m, block_b), lambda c, e: (e, c))
     return pl.pallas_call(
-        kern,
-        grid=(b // block_b,),
-        in_specs=[
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((block_b, m), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, m), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, m), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
+        functools.partial(_netsim_kernel, block_m=block_m),
+        grid=(b // block_b, m // block_m),
+        in_specs=[scalars, scalars, scalars, events, events,
+                  pl.BlockSpec((1, block_b), lambda c, e: (0, c))],
+        out_specs=events,
+        out_shape=jax.ShapeDtypeStruct((m, b), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((block_b, n_pad), jnp.float32),
-            pltpu.VMEM((block_b, n_pad), jnp.float32),
+            pltpu.VMEM((n_pad, block_b), jnp.float32),
+            pltpu.VMEM((n_pad, block_b), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(dnow, src, dst, svc, admit, pipe)

@@ -52,7 +52,9 @@ __all__ = [
     "kernel_available", "resolve_use_kernel",
 ]
 
-LANES = 128          # TPU vector lane width: port axis pads to a multiple
+LANES = 128          # TPU vector lane width: the tile's candidate block
+SUBLANES = 8         # the tile's port axis pads to a multiple of this
+EVENT_BLOCK = 1024   # events per tile grid step (the SMEM timeline block)
 
 
 def kernel_available() -> bool:
@@ -252,8 +254,6 @@ def _sharded_round1(mesh, n_ports):
     — so each shard is bitwise the single-device call on its slice."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     names = tuple(mesh.axis_names)
     cand = P(names)
     rep = P()
@@ -261,17 +261,15 @@ def _sharded_round1(mesh, n_ports):
     name = (f"netsim.kernel.round1.sharded["
             f"{'x'.join(map(str, mesh.devices.shape))} "
             f"{','.join(names)} n_ports={n_ports}]")
-    return track(name, jax.jit(compat.shard_map(
-        body, mesh,
+    return track(name, jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(rep, rep, rep, P(None, names), cand, cand, rep, rep, rep),
-        out_specs=(cand, cand))))
+        out_specs=(cand, cand), check_vma=False)))
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_gated_replay(mesh, n_ports):
     from jax.sharding import PartitionSpec as P
-
-    from repro import compat
 
     names = tuple(mesh.axis_names)
     cand = P(names)
@@ -284,24 +282,24 @@ def _sharded_gated_replay(mesh, n_ports):
     name = (f"netsim.kernel.replay.sharded["
             f"{'x'.join(map(str, mesh.devices.shape))} "
             f"{','.join(names)} n_ports={n_ports}]")
-    return track(name, jax.jit(compat.shard_map(
-        body, mesh,
+    return track(name, jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(rep, rep, rep, cand, cand, cand),
-        out_specs=cand)))
+        out_specs=cand, check_vma=False)))
 
 
 def lean_replay(now, src, dst, svc, pipe, admit, *, n_ports: int,
-                use_pallas: bool = False, interpret: bool = True,
-                block_b: int = 8):
+                use_pallas: bool = False, interpret: bool = False,
+                block_b: int = LANES):
     """The admission-gated lean replay, oracle or Pallas tile.
 
     Oracle path (default): the jitted float64 ``lax.scan``
     (``ref.netsim_replay_abs_ref``), absolute departure times, bit-exact
     against the serial model.  Pallas path: the float32 slack-formulation
-    kernel with the candidate axis tiled onto the grid; returns departure
-    *offsets* (``end − now``), parity at float32 tolerance.  ``interpret``
-    validates the tile on CPU; ``interpret=False`` compiles it for a real
-    TPU backend."""
+    kernel with the candidate and event axes tiled onto the grid; returns
+    departure *offsets* (``end − now``), parity at float32 tolerance.
+    ``interpret=True`` runs the tile in the Pallas interpreter (how the CPU
+    tests validate it); the default compiles it for the TPU."""
     if not use_pallas:
         return netsim_replay_abs_ref(
             jnp.asarray(now), jnp.asarray(src, jnp.int32),
@@ -309,21 +307,29 @@ def lean_replay(now, src, dst, svc, pipe, admit, *, n_ports: int,
             jnp.asarray(pipe), jnp.asarray(admit), n_ports=n_ports)
     now = np.asarray(now, np.float64)
     b_n, m = np.asarray(svc).shape
-    n_pad = -(-n_ports // LANES) * LANES
+    n_pad = -(-n_ports // SUBLANES) * SUBLANES
     b_pad = -(-b_n // block_b) * block_b
-    dnow = np.diff(now, prepend=0.0).astype(np.float32)[None, :]
-    svc_p = np.zeros((b_pad, m), np.float32)
-    svc_p[:b_n] = np.asarray(svc, np.float32)
-    ad_p = np.zeros((b_pad, m), np.float32)
-    ad_p[:b_n] = np.asarray(admit, np.float32)
-    pipe_p = np.zeros((b_pad, 1), np.float32)
-    pipe_p[:b_n, 0] = np.asarray(pipe, np.float32)
+    m_pad = -(-m // EVENT_BLOCK) * EVENT_BLOCK
+    # event-major [m, B] blocks; pad events sit after every real one with
+    # admit = 0, so they never touch the port state real events read
+    dnow = np.zeros(m_pad, np.float32)
+    dnow[:m] = np.diff(now, prepend=0.0)
+    src_p = np.zeros(m_pad, np.int32)
+    src_p[:m] = src
+    dst_p = np.zeros(m_pad, np.int32)
+    dst_p[:m] = dst
+    svc_p = np.zeros((m_pad, b_pad), np.float32)
+    svc_p[:m, :b_n] = np.asarray(svc, np.float32).T
+    ad_p = np.zeros((m_pad, b_pad), np.float32)
+    ad_p[:m, :b_n] = np.asarray(admit, np.float32).T
+    pipe_p = np.zeros((1, b_pad), np.float32)
+    pipe_p[0, :b_n] = np.asarray(pipe, np.float32)
     dep = netsim_replay_padded(
-        jnp.asarray(dnow), jnp.asarray(src, jnp.int32)[None, :],
-        jnp.asarray(dst, jnp.int32)[None, :], jnp.asarray(svc_p),
-        jnp.asarray(ad_p), jnp.asarray(pipe_p),
-        n_pad=n_pad, block_b=block_b, interpret=interpret)
-    return dep[:b_n]
+        jnp.asarray(dnow), jnp.asarray(src_p), jnp.asarray(dst_p),
+        jnp.asarray(svc_p), jnp.asarray(ad_p), jnp.asarray(pipe_p),
+        n_pad=n_pad, block_b=block_b, block_m=EVENT_BLOCK,
+        interpret=interpret)
+    return dep[:m, :b_n].T
 
 
 def _pad_rows(a: np.ndarray, size: int) -> np.ndarray:

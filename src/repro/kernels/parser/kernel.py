@@ -47,7 +47,7 @@ def make_parser(
     field_names: Sequence[str],
     *,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Callable[[jnp.ndarray], jnp.ndarray]:
     """Generate the specialised parser kernel for (protocol, fields)."""
     baked = bake_slices(protocol, field_names)

@@ -21,7 +21,7 @@ def parse_headers(
     *,
     use_pallas: bool = True,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Returns uint32 [B, len(field_names)] parsed field values."""
     b, w = words.shape

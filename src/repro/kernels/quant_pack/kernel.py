@@ -42,7 +42,7 @@ def _dequant_kernel(q_ref, s_ref, x_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def quantize(x: jnp.ndarray, *, block_rows: int = 256, interpret: bool = True):
+def quantize(x: jnp.ndarray, *, block_rows: int = 256, interpret: bool = False):
     """x [R, C] (C % 128 == 0) -> (q int8 [R, C], scales f32 [R, C/128])."""
     r, c = x.shape
     assert c % GROUP == 0, f"last dim {c} must be a multiple of {GROUP}"
@@ -67,7 +67,7 @@ def quantize(x: jnp.ndarray, *, block_rows: int = 256, interpret: bool = True):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "out_dtype", "interpret"))
 def dequantize(q: jnp.ndarray, s: jnp.ndarray, *, block_rows: int = 256,
-               out_dtype=jnp.float32, interpret: bool = True):
+               out_dtype=jnp.float32, interpret: bool = False):
     r, c = q.shape
     br = min(block_rows, r)
     assert r % br == 0

@@ -1,9 +1,9 @@
 """Public ops for payload compression, with a jax-native fallback.
 
 ``compress``/``decompress`` round-trip arbitrary-shaped tensors by flattening
-to [R, 128k].  On CPU the Pallas kernel runs in interpret mode; inside
-jit-for-dryrun graphs we use the pure-jnp reference (identical math) so the
-HLO compiles on any backend — the kernel is the TPU deployment path.
+to [R, 128k].  The default is the pure-jnp reference (identical math), so
+the HLO compiles on any backend; ``use_pallas=True`` takes the Pallas kernel,
+the TPU deployment path.
 """
 
 from __future__ import annotations

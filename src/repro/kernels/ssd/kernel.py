@@ -70,7 +70,7 @@ def ssd_scan(
     c: jnp.ndarray,    # [BH, S, N]
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     bh, s, p = x.shape
     n = b.shape[-1]

@@ -58,7 +58,7 @@ def ssd_chunked(x, dt, a, b, c, *, chunk: int = 128, return_state: bool = False)
 
 
 def ssd(x, dt, a, b, c, *, chunk: int = 128, use_pallas: bool = False,
-        interpret: bool = True) -> jnp.ndarray:
+        interpret: bool = False) -> jnp.ndarray:
     if use_pallas:
         return ssd_scan(x, dt, a, b, c, chunk=chunk, interpret=interpret)
     return ssd_chunked(x, dt, a, b, c, chunk=chunk)

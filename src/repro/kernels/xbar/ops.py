@@ -15,11 +15,12 @@ import jax.numpy as jnp
 from .kernel import xbar_contend_padded
 from .ref import xbar_contend_abs_ref, xbar_contend_slack_ref
 
-LANES = 128
+SUBLANES = 8     # the port axis of the tile's state pads to a multiple
+EVENT_BLOCK = 1024   # events per grid step (the SMEM timeline block)
 
 
 def xbar_contend(t, dt, src, dst, svc, *, n_ports: int, use_pallas: bool = False,
-                 block_b: int = 8, interpret: bool = True,
+                 block_b: int = 128, interpret: bool = False,
                  absolute: bool = None):
     """t/dt/src/dst [m] shared trace, svc [B, m] -> [B, m] departure times
     (absolute on the float64 path, arrival-relative offsets on float32).
@@ -36,19 +37,21 @@ def xbar_contend(t, dt, src, dst, svc, *, n_ports: int, use_pallas: bool = False
             f"and use_pallas=False); got dtype {jnp.asarray(svc).dtype}")
     if use_pallas:
         b, m = svc.shape
-        n_pad = -(-n_ports // LANES) * LANES
-        pad_b = (-b) % block_b
-        svc32 = jnp.asarray(svc, jnp.float32)
-        if pad_b:
-            svc32 = jnp.pad(svc32, ((0, pad_b), (0, 0)))
+        n_pad = -(-n_ports // SUBLANES) * SUBLANES
+        pad_m = (-m) % EVENT_BLOCK
+        # event-major [m, B]; tail pad events come after every real one, so
+        # the port state they disturb is never read back
+        svc_t = jnp.pad(jnp.asarray(svc, jnp.float32).T,
+                        ((0, pad_m), (0, (-b) % block_b)))
         dep = xbar_contend_padded(
-            jnp.asarray(dt, jnp.float32)[None, :],
-            jnp.asarray(src, jnp.int32)[None, :],
-            jnp.asarray(dst, jnp.int32)[None, :],
-            svc32,
-            n_pad=n_pad, block_b=block_b, interpret=interpret,
+            jnp.pad(jnp.asarray(dt, jnp.float32), (0, pad_m)),
+            jnp.pad(jnp.asarray(src, jnp.int32), (0, pad_m)),
+            jnp.pad(jnp.asarray(dst, jnp.int32), (0, pad_m)),
+            svc_t,
+            n_pad=n_pad, block_b=block_b, block_m=EVENT_BLOCK,
+            interpret=interpret,
         )
-        return dep[:b]
+        return dep[:m, :b].T
     if absolute:
         return xbar_contend_abs_ref(t, src, dst, svc, n_ports=n_ports)
     return xbar_contend_slack_ref(dt, src, dst, svc, n_ports=n_ports)

@@ -16,7 +16,7 @@ from typing import Optional
 import jax
 import numpy as np
 
-__all__ = ["compat_make_mesh", "make_production_mesh", "plan_for_mesh",
+__all__ = ["make_mesh", "make_production_mesh", "plan_for_mesh",
            "N_DEVICES", "MeshSpec", "padded_size", "shard_pad", "shard_unpad"]
 
 N_DEVICES = {"single": 256, "multi": 512}
@@ -39,20 +39,15 @@ def _validate_mesh_shape(shape, axes):
             f"to simulate more on CPU)")
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types across JAX versions.
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``.
 
-    ``axis_types=`` / ``jax.sharding.AxisType`` only exist on newer releases;
-    older ones (0.4.x) behave as Auto everywhere, which is what we want.
     Raises ``ValueError`` (with both numbers named) for zero-extent axes or
     shapes larger than the available device count instead of letting jax
     build a sharding that silently misassigns data."""
     _validate_mesh_shape(shape, axes)
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # --------------------------------------------------------------------------
@@ -107,7 +102,7 @@ class MeshSpec:
         key = (self.scenario_axis, self.devices)
         mesh = _MESH_CACHE.get(key)
         if mesh is None:
-            mesh = compat_make_mesh(key, ("scenario", "cand"))
+            mesh = make_mesh(key, ("scenario", "cand"))
             _MESH_CACHE[key] = mesh
         return mesh
 
@@ -162,11 +157,11 @@ def shard_unpad(a, n: int, axis: int = 0):
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(data: int = 1, model: int = 1):
-    return compat_make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def plan_for_mesh(mesh):

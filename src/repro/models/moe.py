@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.kernels.quant_pack import ref as qref
 from .config import ModelConfig, ShardingPlan
 from .layers import dense_init
@@ -281,7 +280,7 @@ def apply_moe(
 
     in_specs = (P(dp, None, None), moe_in_specs(plan, opts.weights))
     out_specs = (P(dp, None, None), P(), P(), P())
-    y, aux, drops, occ = compat.shard_map(
-        f, mesh, in_specs=in_specs, out_specs=out_specs, check=False,
+    y, aux, drops, occ = jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )(x, {k: params[k] for k in ("router", "hash_proj", "w1", "wg", "w2")})
     return y, {"aux_loss": aux, "drop_frac": drops, "expert_load": occ}

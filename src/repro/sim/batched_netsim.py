@@ -57,7 +57,6 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.analysis.retrace import track
 from repro.core.archspec import SwitchArch, VOQKind
@@ -131,8 +130,6 @@ def _sharded_verify_engine(mesh, n_ports, d_max):
     slice."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     names = tuple(mesh.axis_names)
     cand = P(names)
     rep = P()
@@ -140,10 +137,10 @@ def _sharded_verify_engine(mesh, n_ports, d_max):
                              d_max=d_max)
     name = (f"netsim.sharded[{'x'.join(map(str, mesh.devices.shape))} "
             f"{','.join(names)} n_ports={n_ports} d_max={d_max}]")
-    return track(name, jax.jit(compat.shard_map(
-        body, mesh,
+    return track(name, jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(rep, rep, rep, P(None, names), cand, cand, cand),
-        out_specs=(cand, cand))))
+        out_specs=(cand, cand), check_vma=False)))
 
 
 def _shared_cap_ok(admit_b: np.ndarray, sorted_ends_b: np.ndarray,
@@ -227,7 +224,7 @@ def _run_group(archs, bounds, trace, hw_list, cfg,
     if k > 1:
         from repro.launch.mesh import shard_pad
         svc_p = shard_pad(svc, k)
-        with enable_x64():
+        with jax.enable_x64():
             end, admit = _sharded_verify_engine(mesh_spec.build(), n, d_max)(
                 jnp.asarray(now), jnp.asarray(tl4.src_o, jnp.int32),
                 jnp.asarray(tl4.dst_o, jnp.int32),
@@ -236,7 +233,7 @@ def _run_group(archs, bounds, trace, hw_list, cfg,
                 jnp.asarray(shard_pad(depth, k), jnp.int32),
                 jnp.asarray(shard_pad(mod, k)))
     else:
-        with enable_x64():
+        with jax.enable_x64():
             end, admit = _verify_engine(
                 jnp.asarray(now), jnp.asarray(tl4.src_o, jnp.int32),
                 jnp.asarray(tl4.dst_o, jnp.int32), jnp.asarray(svc[:, order].T),
@@ -369,7 +366,7 @@ def _run_group_kernel(archs, bounds, trace, hw_list, cfg,
     uniq_res: List[Optional[VerifyResult]] = []
     if uniq_rows:
         ui = np.asarray(uniq_rows)
-        with enable_x64():
+        with jax.enable_x64():
             end, admit, conv, _rounds = netsim_fixed_point(
                 now, tl4.src_o.astype(np.int32), tl4.dst_o.astype(np.int32),
                 svc_e[ui], pipe[ui], depth[ui], n_ports=n, chain=tl4.chain,

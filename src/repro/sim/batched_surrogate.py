@@ -20,7 +20,7 @@ ingress stalls, f_clk) is a batch axis:
     serial path, so stage-3 sizing and drop counts cannot drift.
 
 Precision: with ``precision="float64"`` (default) the scan runs under a
-scoped ``jax.experimental.enable_x64`` so departure times match the serial
+scoped ``jax.enable_x64`` so departure times match the serial
 float64 model exactly; ``precision="float32"`` keeps TPU-native dtypes (the
 scan carries arrival-relative *slacks*, never absolute timestamps, so f32
 still holds queueing-delay precision on arbitrarily long traces).
@@ -35,7 +35,6 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.analysis.retrace import track
 from repro.core.archspec import SwitchArch, VOQKind
@@ -89,18 +88,16 @@ def _sharded_engine(mesh, n_ports, use_pallas, interpret):
     the result is bitwise-identical to the single-device call."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     cand = P(tuple(mesh.axis_names))
     rep = P()
     body = functools.partial(_engine_impl, n_ports=n_ports,
                              use_pallas=use_pallas, interpret=interpret)
     name = (f"surrogate.sharded[{'x'.join(map(str, mesh.devices.shape))} "
             f"{','.join(mesh.axis_names)} n_ports={n_ports}]")
-    return track(name, jax.jit(compat.shard_map(
-        body, mesh,
+    return track(name, jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(rep, rep, rep, cand, rep, cand),
-        out_specs=(cand, cand))))
+        out_specs=(cand, cand), check_vma=False)))
 
 
 def _exact_occupancy(t, qid, dep):
@@ -261,7 +258,7 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
                                        use_pallas=use_pallas,
                                        interpret=interpret)
         if precision == "float64":
-            with enable_x64():
+            with jax.enable_x64():
                 dep, thru = engine(*args)
                 dep, thru = np.asarray(dep), np.asarray(thru)
         else:
@@ -309,7 +306,7 @@ def run_surrogate_batched(
     back_annotation: bool = False,
     i_burst: float = 1.0,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     precision: str = "float64",
     quantiles: Sequence[float] = DEFAULT_QUANTILES,
     mesh=None,
@@ -333,9 +330,9 @@ def run_surrogate_batched(
     structural axis, so mixed-port batches are partitioned internally and the
     per-group results are stitched back in input order.
 
-    ``use_pallas`` selects the Pallas crossbar kernel (float32);
-    ``interpret=True`` (the default) validates it on CPU, ``interpret=False``
-    compiles it for a real TPU backend.
+    ``use_pallas`` selects the Pallas crossbar kernel (float32), compiled
+    for the TPU; ``interpret=True`` runs it in the Pallas interpreter
+    instead (how the CPU tests validate it).
 
     ``use_kernel`` (``"auto"``/``"on"``/``"off"`` or a bool) switches the
     exact occupancy count to the segmented flat-searchsorted kernel

@@ -9,7 +9,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax  # noqa: E402
 
-from repro.launch.mesh import compat_make_mesh  # noqa: E402
+# the CLI entry points turn JAX's persistent compilation cache on; the tests
+# that drive them must not leave compiled programs behind in the checkout
+jax.config.update("jax_enable_compilation_cache", False)
+
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 import pytest  # noqa: E402
 
@@ -23,4 +27,4 @@ def pytest_addoption(parser):
 
 @pytest.fixture(scope="session")
 def mesh11():
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

@@ -423,6 +423,16 @@ def test_retrace_guard_raises_on_mismatch():
             pass
 
 
+def test_unreadable_jit_cache_raises(monkeypatch):
+    """A tracked callable without a jit cache must fail the guard loudly —
+    reading it as 0 would let a zero-compilations check pass unseen."""
+    from repro.analysis import retrace
+
+    monkeypatch.setitem(retrace._TRACKED, "not.jitted", lambda x: x)
+    with pytest.raises(TypeError, match="no jit cache size"):
+        retrace.compile_counts()
+
+
 def _run_forced(code, devices=2):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"

@@ -119,7 +119,8 @@ def test_use_pallas_coerces_precision():
     the bit-exact float64 contract still holds."""
     tr = hft(seed=0).head(64)
     batch = run_surrogate_batched(_candidates()[:2], BOUND, tr,
-                                  back_annotation=False, use_pallas=True)
+                                  back_annotation=False, use_pallas=True,
+                                  interpret=True)
     assert batch.meta["precision"] == "float32"
 
 
@@ -153,14 +154,18 @@ def test_surrogate_batch_misalignment_raises():
                 ResourceBudget(dict(ALVEO_U45N)))
 
 
-def test_pallas_xbar_matches_slack_oracle():
+@pytest.mark.parametrize("m,b,n,seed,horizon", [
+    (160, 10, 8, 3, 1e-5),
+    # two candidate blocks, three event blocks (the last padded): port
+    # slacks must carry across event blocks
+    (2500, 130, 32, 9, 3e-5)], ids=["one_block", "across_blocks"])
+def test_pallas_xbar_matches_slack_oracle(m, b, n, seed, horizon):
     import jax.numpy as jnp
     from repro.kernels.xbar import xbar_contend
     from repro.kernels.xbar.ref import xbar_contend_slack_ref
 
-    m, b, n = 160, 10, 8
-    rng = np.random.default_rng(3)
-    t = np.sort(rng.uniform(0, 1e-5, m))
+    rng = np.random.default_rng(seed)
+    t = np.sort(rng.uniform(0, horizon, m))
     dt = np.diff(t, prepend=t[:1]).astype(np.float32)
     src = rng.integers(0, n, m).astype(np.int32)
     dst = rng.integers(0, n, m).astype(np.int32)
@@ -169,7 +174,7 @@ def test_pallas_xbar_matches_slack_oracle():
                                  jnp.asarray(dst), jnp.asarray(svc), n_ports=n)
     pal = xbar_contend(jnp.asarray(t, jnp.float32), jnp.asarray(dt),
                        jnp.asarray(src), jnp.asarray(dst), jnp.asarray(svc),
-                       n_ports=n, use_pallas=True)
+                       n_ports=n, use_pallas=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(pal), np.asarray(ref))
 
 
@@ -230,7 +235,7 @@ def test_comm_surrogate_batch_matches_scalar_reference():
     from repro.comm.dse_comm import CommDSEProblem
     from repro.models.config import ModelConfig, ShardingPlan
     from repro.models.moe import init_moe
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
 
     cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=128,
                       n_heads=4, n_kv_heads=2, d_ff=256, vocab=256,
@@ -238,7 +243,7 @@ def test_comm_surrogate_batch_matches_scalar_reference():
     plan = ShardingPlan()
     params, _ = init_moe(jax.random.PRNGKey(0), cfg, plan)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 128))
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     prob = CommDSEProblem(params, cfg, plan, mesh, x, model_tp=8)
     cands = prob.candidates()
     assert len(cands) >= 8

@@ -16,7 +16,7 @@ from repro.core import ethernet_ipv4_udp, compressed_protocol, Field, Protocol
 def test_quantize_matches_ref(shape, dtype):
     from repro.kernels.quant_pack import kernel, ref
     x = jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
-    q1, s1 = kernel.quantize(x)
+    q1, s1 = kernel.quantize(x, interpret=True)
     q2, s2 = ref.quantize_ref(x)
     np.testing.assert_array_equal(np.asarray(q1), np.asarray(q2))
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-6)
@@ -25,8 +25,8 @@ def test_quantize_matches_ref(shape, dtype):
 def test_quantize_roundtrip_error_bounded():
     from repro.kernels.quant_pack import kernel
     x = jax.random.normal(jax.random.PRNGKey(1), (128, 256), jnp.float32)
-    q, s = kernel.quantize(x)
-    xr = kernel.dequantize(q, s)
+    q, s = kernel.quantize(x, interpret=True)
+    xr = kernel.dequantize(q, s, interpret=True)
     group_max = np.abs(np.asarray(x)).reshape(128, 2, 128).max(-1)
     bound = np.repeat(group_max / 127.0, 128, axis=-1).reshape(128, 256) * 0.5 + 1e-6
     assert (np.abs(np.asarray(xr) - np.asarray(x)) <= bound).all()
@@ -58,7 +58,7 @@ def test_parser_kernel_matches_ref(proto_fn, fields, n):
     vals = {f.name: rng.integers(0, min(1 << f.bits, 1 << 31), n, dtype=np.uint64)
             for f in proto.fields}
     words = jnp.asarray(pack_header_words(proto, vals))
-    out_k = parse_headers(proto, fields, words, use_pallas=True)
+    out_k = parse_headers(proto, fields, words, use_pallas=True, interpret=True)
     out_r = parse_ref(proto, fields, words)
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 
@@ -82,7 +82,7 @@ def test_parser_kernel_random_protocols(pv):
     proto, vals = pv
     words = jnp.asarray(pack_header_words(proto, vals))
     names = [f.name for f in proto.fields]
-    out = parse_headers(proto, names, words, use_pallas=True)
+    out = parse_headers(proto, names, words, use_pallas=True, interpret=True)
     for i, f in enumerate(proto.fields):
         np.testing.assert_array_equal(np.asarray(out[:, i]),
                                       vals[f.name].astype(np.uint32))
@@ -98,7 +98,8 @@ def test_flash_attention_matches_ref(s, d, hq, hkv, causal):
     q = jax.random.normal(k1, (2, hq, s, d), jnp.float32)
     k = jax.random.normal(k2, (2, hkv, s, d), jnp.float32)
     v = jax.random.normal(k3, (2, hkv, s, d), jnp.float32)
-    o1 = flash_attention(q, k, v, causal=causal, block_q=64, block_k=128)
+    o1 = flash_attention(q, k, v, causal=causal, block_q=64, block_k=128,
+                         interpret=True)
     o2 = attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=3e-5, rtol=3e-5)
 
@@ -109,7 +110,7 @@ def test_flash_attention_bf16():
     q = jax.random.normal(k1, (1, 4, 128, 64), jnp.bfloat16)
     k = jax.random.normal(k2, (1, 4, 128, 64), jnp.bfloat16)
     v = jax.random.normal(k3, (1, 4, 128, 64), jnp.bfloat16)
-    o1 = flash_attention(q, k, v, block_q=64, block_k=64)
+    o1 = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
     o2 = attention_reference(q, k, v)
     assert float(jnp.abs(o1.astype(jnp.float32) - o2.astype(jnp.float32)).max()) < 0.05
 
@@ -122,7 +123,8 @@ def test_xla_blockwise_matches_pallas():
     k = jax.random.normal(k2, (2, 2, 256, 64), jnp.float32)
     v = jax.random.normal(k3, (2, 2, 256, 64), jnp.float32)
     o1 = blockwise_attention(q, k, v, causal=True, block_q=64, block_k=64)
-    o2 = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    o2 = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                         interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=3e-5)
 
 
@@ -143,7 +145,8 @@ def test_ssd_kernel_and_chunked_match_ref(s, p, n, chunk):
     ref = ssd_reference(x, dt, a, b, c)
     np.testing.assert_allclose(np.asarray(ssd_chunked(x, dt, a, b, c, chunk=chunk)),
                                np.asarray(ref), atol=2e-3, rtol=2e-3)
-    np.testing.assert_allclose(np.asarray(ssd_scan(x, dt, a, b, c, chunk=chunk)),
+    np.testing.assert_allclose(np.asarray(ssd_scan(x, dt, a, b, c, chunk=chunk,
+                                                    interpret=True)),
                                np.asarray(ref), atol=2e-3, rtol=2e-3)
 
 
@@ -174,7 +177,7 @@ def test_islip_kernel_matches_lax_scheduler(n, iters):
     req = jnp.asarray(rng.integers(0, 2, (B, n, n)), jnp.int32)
     g = jnp.asarray(rng.integers(0, n, (B, n)), jnp.int32)
     a = jnp.asarray(rng.integers(0, n, (B, n)), jnp.int32)
-    m1, g1, a1 = islip_schedule(req, g, a, iters=iters, use_pallas=True)
+    m1, g1, a1 = islip_schedule(req, g, a, iters=iters, use_pallas=True, interpret=True)
     m2, g2, a2 = islip_schedule(req, g, a, iters=iters, use_pallas=False)
     np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
     np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
@@ -189,7 +192,7 @@ def test_islip_kernel_match_validity_property(bits, iters):
     req = jnp.asarray([(bits >> i) & 1 for i in range(n * n)], jnp.int32).reshape(1, n, n)
     g = jnp.zeros((1, n), jnp.int32)
     a = jnp.zeros((1, n), jnp.int32)
-    m, _, _ = islip_schedule(req, g, a, iters=iters, use_pallas=True)
+    m, _, _ = islip_schedule(req, g, a, iters=iters, use_pallas=True, interpret=True)
     m = np.asarray(m[0])
     assert (m.sum(0) <= 1).all() and (m.sum(1) <= 1).all()
     assert not (m & ~np.asarray(req[0]).astype(bool)).any()

@@ -121,7 +121,7 @@ def test_nsga2_front_identical_across_device_counts():
 import json
 from repro.api import registry, run_scenario
 from repro.api.scenario import MeshSpec, SearchSpec
-from tests.test_golden import diff_reports
+from repro.api.golden import diff_reports
 
 scn = registry["hft"].override(
     back_annotation=False, search=SearchSpec(population=16, generations=3, seed=7))
@@ -191,13 +191,13 @@ for n, m in ((8, 2), (2, 8)):
 def test_mesh_validation_names_both_numbers():
     import jax
 
-    from repro.launch.mesh import MeshSpec, compat_make_mesh
+    from repro.launch.mesh import MeshSpec, make_mesh
 
     with pytest.raises(ValueError, match=r"extent 0"):
-        compat_make_mesh((0, 1), ("scenario", "cand"))
+        make_mesh((0, 1), ("scenario", "cand"))
     avail = jax.device_count()
     with pytest.raises(ValueError) as ei:
-        compat_make_mesh((avail + 1, 1), ("scenario", "cand"))
+        make_mesh((avail + 1, 1), ("scenario", "cand"))
     assert str(avail + 1) in str(ei.value) and str(avail) in str(ei.value)
     with pytest.raises(ValueError, match=r"size 0"):
         MeshSpec(devices=0)

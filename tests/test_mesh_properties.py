@@ -131,7 +131,7 @@ def _assert_state_equal(a, b, *, compare_mesh=True):
 @given(n=st.integers(min_value=1, max_value=64),
        m=st.integers(min_value=1, max_value=64))
 def test_remesh_roundtrip_identity_property(n, m):
-    tree, extra = _searched_state()
+    tree, extra = _searched_state().state()
     start = remesh_search_state(tree, extra, MeshSpec(devices=n))
     via_m = remesh_search_state(*start, MeshSpec(devices=m))
     back = remesh_search_state(*via_m, MeshSpec(devices=n))

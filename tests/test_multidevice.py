@@ -5,16 +5,7 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-sys.path.insert(0, os.path.join(REPO, "src"))
-from repro import compat  # noqa: E402
-
-needs_partial_manual = pytest.mark.skipif(
-    not compat.has_partial_manual_shard_map(),
-    reason="partial-manual shard_map (pod protocol) unsupported on jax<=0.4.x")
 
 
 def _run(code: str, devices: int = 8) -> str:
@@ -31,7 +22,7 @@ def test_moe_fabric_sharded_equals_single_device():
     """The switch-fabric MoE must be invariant to the mesh layout."""
     _run("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig, ShardingPlan
 from repro.models.moe import init_moe, apply_moe, MoEOptions
 cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=128, n_heads=4,
@@ -42,7 +33,7 @@ params, _ = init_moe(jax.random.PRNGKey(0), cfg, plan)
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 32, 128), jnp.float32).astype(jnp.bfloat16)
 outs = []
 for shape in [(1, 1), (2, 4), (4, 2), (8, 1)]:
-    mesh = compat_make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     y, aux = apply_moe(params, cfg, plan, mesh, x)
     outs.append(np.asarray(y.astype(jnp.float32)))
 for o in outs[1:]:
@@ -51,27 +42,24 @@ print("fabric mesh-invariant OK")
 """)
 
 
-@needs_partial_manual
 def test_compressed_pod_protocol_close_to_exact_mean():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.comm.protocols import compressed_mean
-mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 g = jax.random.normal(jax.random.PRNGKey(0), (2, 4, 256), jnp.float32)
 
 def f(g):
-    # the pod-sharded input already differs per pod member (axis_index over a
-    # manual axis is unsupported under 0.4.x partial-manual shard_map)
+    # the pod-sharded input already differs per pod member
     local = g * (1.0 + jnp.abs(g).mean())          # pod-varying gradients
     exact = jax.lax.pmean(local, "pod")
     comp = compressed_mean({"g": local}, "pod")["g"]
     return exact, comp
-from repro import compat
-exact, comp = jax.jit(compat.shard_map(f, mesh, axis_names={"pod"},
-                                       in_specs=P("pod"), out_specs=(P(), P()),
-                                       check=False))(g)
+exact, comp = jax.jit(jax.shard_map(f, mesh=mesh, axis_names={"pod"},
+                                    in_specs=P("pod"), out_specs=(P(), P()),
+                                    check_vma=False))(g)
 err = float(jnp.abs(exact - comp).max())
 scale = float(jnp.abs(exact).max())
 assert err < 0.02 * scale, (err, scale)
@@ -79,17 +67,16 @@ print("compressed pod mean OK", err)
 """)
 
 
-@needs_partial_manual
 def test_train_step_with_compressed_pod_grads_runs():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_smoke
 from repro.models.config import MULTI_POD_PLAN
 from repro.models import transformer as T
 from repro.train import adamw, make_train_step, TrainSpec
 from repro.data import DataConfig, SyntheticLM
-mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_smoke("llama3.2-1b")
 plan = MULTI_POD_PLAN
 params, _ = T.init_params(jax.random.PRNGKey(0), cfg, plan)

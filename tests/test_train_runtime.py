@@ -7,7 +7,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 import numpy as np
 import pytest
 
@@ -26,7 +26,7 @@ PLAN = SINGLE_POD_PLAN
 
 @pytest.fixture(scope="module")
 def setup(request):
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_smoke("llama3.2-1b")
     params, specs = T.init_params(jax.random.PRNGKey(0), cfg, PLAN)
     data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=64, global_batch=4))
@@ -142,7 +142,7 @@ def test_straggler_watch_fires():
 
 def test_elastic_remesh_roundtrip(setup):
     mesh, cfg, params, specs, _ = setup
-    new_mesh = compat_make_mesh((1, 1), ("data", "model"))
+    new_mesh = make_mesh((1, 1), ("data", "model"))
     moved = remesh(params, specs, new_mesh)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(moved)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
