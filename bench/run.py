@@ -1,0 +1,18 @@
+"""Entry point of the on-chip benchmark; see ``harness.py``.
+
+    python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+"""
+
+import time
+
+T_START = time.perf_counter()
+
+import os  # noqa: E402
+import sys  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from harness import main  # noqa: E402
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:], t_start=T_START))
