@@ -22,9 +22,9 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
+from repro.analysis.spans import note, span
 from repro.core.binding import BoundProtocol, bind
 from repro.core.dse import (DSEProblem, DSEResult, ResourceBudget, SLA,
                             StageLog, SurrogateResult, VerifyResult,
@@ -120,12 +120,18 @@ def build_problem(
     shards the batched stages across the device mesh, with results
     bit-identical to the serial default.
     """
+    with span("spac.build"):
+        return _build_problem(scenario, trace, features, mesh)
+
+
+def _build_problem(scenario: Scenario, trace, features, mesh):
     mesh = MeshSpec.coerce(mesh) if mesh is not None else scenario.mesh
     budget = scenario.budget or _default_budget(scenario)
     if scenario.domain == "comm":
         return _build_comm_problem(scenario), scenario.sla, budget
     from repro.sim.switch_problem import SwitchDSEProblem
     tr = trace if trace is not None else scenario.trace.build()
+    note(events=len(tr))
     if scenario.topology is not None:
         from repro.fabric import FabricDSEProblem
         topo = scenario.topology.build()
@@ -417,47 +423,48 @@ def run_scenario(scenario: Union[Scenario, str], *, verbose: bool = False,
     """
     if isinstance(scenario, str):
         scenario = registry[scenario]
-    t0 = time.perf_counter()
-    problem, sla, budget = build_problem(scenario, mesh=mesh)
-    fid = scenario.fidelity
-    if scenario.search is not None:
-        t2 = time.perf_counter()
-        outcome = run_search(problem, scenario.search, sla, delta=fid.delta,
-                             checkpoint_dir=_search_checkpoint_dir(scenario),
-                             resume=resume)
-        stage2_time = time.perf_counter() - t2
-        valid, pre_logs = outcome.valid, [outcome.log]
-        stage2_cands = outcome.surrogate_rows
-        if verbose:
-            print(outcome.log)
-    else:
-        active, log1 = stage1_static(problem, delta=fid.delta)
-        if verbose:
-            print(log1)
-        t2 = time.perf_counter()
-        srs = problem.surrogate_batch(active)
-        stage2_time = time.perf_counter() - t2
-        valid, log2 = stage2_screen(problem, active, sla, surrogates=srs)
-        if verbose:
-            print(log2)
-        pre_logs = [log1, log2]
-        stage2_cands = len(active)
-    sized, n_explored = stage3_size(problem, valid, sla, budget, top_k=fid.top_k)
-    t4 = time.perf_counter()
-    verifies = problem.verify_batch([a for a, _ in sized])
-    stage4_time = time.perf_counter() - t4
-    evaluated, best, best_v = stage4_verify(problem, sized, sla,
-                                            verifies=verifies)
-    log3 = StageLog("stage3-sizing+verify", n_explored, len(sized))
-    if verbose:
-        print(log3)
-    result = finalize_result(problem, evaluated, best, best_v, pre_logs + [log3])
-    return ScenarioReport(scenario=scenario, result=result, problem=problem,
-                          wall_time_s=time.perf_counter() - t0,
-                          stage2_candidates=stage2_cands,
-                          stage2_time_s=stage2_time,
-                          stage4_candidates=len(sized),
-                          stage4_time_s=stage4_time)
+    with span("spac.explore", scenario=scenario.name) as root:
+        problem, sla, budget = build_problem(scenario, mesh=mesh)
+        fid = scenario.fidelity
+        if scenario.search is not None:
+            with span("spac.search") as s2:
+                outcome = run_search(
+                    problem, scenario.search, sla, delta=fid.delta,
+                    checkpoint_dir=_search_checkpoint_dir(scenario),
+                    resume=resume)
+            valid, pre_logs = outcome.valid, [outcome.log]
+            stage2_cands = outcome.surrogate_rows
+            if verbose:
+                print(outcome.log)
+        else:
+            active, log1 = stage1_static(problem, delta=fid.delta)
+            if verbose:
+                print(log1)
+            with span("spac.stage2") as s2:
+                srs = problem.surrogate_batch(active)
+            valid, log2 = stage2_screen(problem, active, sla, surrogates=srs)
+            if verbose:
+                print(log2)
+            pre_logs = [log1, log2]
+            stage2_cands = len(active)
+        sized, n_explored = stage3_size(problem, valid, sla, budget,
+                                        top_k=fid.top_k)
+        with span("spac.stage4") as s4:
+            verifies = problem.verify_batch([a for a, _ in sized])
+        with span("spac.finalize"):
+            evaluated, best, best_v = stage4_verify(problem, sized, sla,
+                                                    verifies=verifies)
+            log3 = StageLog("stage3-sizing+verify", n_explored, len(sized))
+            if verbose:
+                print(log3)
+            result = finalize_result(problem, evaluated, best, best_v,
+                                     pre_logs + [log3])
+            return ScenarioReport(scenario=scenario, result=result,
+                                  problem=problem, wall_time_s=root.seconds,
+                                  stage2_candidates=stage2_cands,
+                                  stage2_time_s=s2.seconds,
+                                  stage4_candidates=len(sized),
+                                  stage4_time_s=s4.seconds)
 
 
 @dataclasses.dataclass
@@ -543,204 +550,209 @@ def run_campaign(
     scns = [registry[s] if isinstance(s, str) else s for s in scenarios]
     if not scns:
         raise ValueError("run_campaign needs at least one scenario")
-    t_start = time.perf_counter()
+    with span("spac.campaign", scenarios=len(scns)) as root:
+        # ---- build: share built traces + feature analysis across scenarios
+        from repro.core.features import analyze
+        trace_cache: Dict[str, Tuple[Any, Any]] = {}
+        ctxs: List[_Ctx] = []
+        with span("spac.build"):
+            for s in scns:
+                if s.domain == "switch":
+                    tkey = s.trace.key()
+                    shared = tkey in trace_cache
+                    if not shared:
+                        tr = s.trace.build()
+                        trace_cache[tkey] = (tr, analyze(tr))
+                    tr, feats = trace_cache[tkey]
+                    problem, _, budget = build_problem(
+                        s, trace=tr, features=feats, mesh=mesh)
+                    ctxs.append(_Ctx(s, problem, budget, shared,
+                                     _switch_group_key(s)))
+                else:
+                    problem, _, budget = build_problem(s, mesh=mesh)
+                    ctxs.append(_Ctx(s, problem, budget, False, None))
 
-    # ---- build: share built traces + feature analysis across scenarios
-    from repro.core.features import analyze
-    trace_cache: Dict[str, Tuple[Any, Any]] = {}
-    ctxs: List[_Ctx] = []
-    for s in scns:
-        if s.domain == "switch":
-            tkey = s.trace.key()
-            shared = tkey in trace_cache
-            if not shared:
-                tr = s.trace.build()
-                trace_cache[tkey] = (tr, analyze(tr))
-            tr, feats = trace_cache[tkey]
-            problem, _, budget = build_problem(s, trace=tr, features=feats,
-                                               mesh=mesh)
-            ctxs.append(_Ctx(s, problem, budget, shared, _switch_group_key(s)))
-        else:
-            problem, _, budget = build_problem(s, mesh=mesh)
-            ctxs.append(_Ctx(s, problem, budget, False, None))
+        # ---- search engines: one driver per searching scenario
+        for ctx in ctxs:
+            s = ctx.scenario
+            if s.search is not None:
+                ctx.driver = SearchDriver(
+                    ctx.problem, s.search, s.sla, delta=s.fidelity.delta,
+                    checkpoint_dir=_search_checkpoint_dir(s, campaign=True),
+                    resume=resume)
 
-    # ---- search engines: one driver per searching scenario
-    for ctx in ctxs:
-        s = ctx.scenario
-        if s.search is not None:
-            ctx.driver = SearchDriver(
-                ctx.problem, s.search, s.sla, delta=s.fidelity.delta,
-                checkpoint_dir=_search_checkpoint_dir(s, campaign=True),
-                resume=resume)
-
-    # ---- stage 1 per scenario (search drivers do their own static pruning)
-    for ctx in ctxs:
-        if ctx.driver is not None:
-            continue
-        t0 = time.perf_counter()
-        ctx.active, ctx.log1 = stage1_static(ctx.problem,
-                                             delta=ctx.scenario.fidelity.delta)
-        ctx.stage1_time_s = time.perf_counter() - t0
-        if verbose:
-            print(f"[{ctx.scenario.name}] {ctx.log1}")
-
-    # ---- stage 2: fan every scenario's survivors through the batched engine;
-    # scenarios sharing (trace, bound, fidelity) share one call
-    groups: Dict[str, List[_Ctx]] = {}
-    order: List[str] = []
-    for i, ctx in enumerate(ctxs):
-        if ctx.driver is not None:
-            continue
-        key = ctx.group_key if ctx.group_key is not None else f"solo-{i}"
-        if key not in groups:
-            groups[key] = []
-            order.append(key)
-        groups[key].append(ctx)
-
-    total_cands = 0
-    stage2_time = 0.0
-    n_batches = 0
-    for key in order:
-        members = groups[key]
-        archs = [a for ctx in members for a in ctx.active]
-        srs: List[SurrogateResult] = []
-        elapsed = 0.0
-        if archs:
-            t0 = time.perf_counter()
-            srs = members[0].problem.surrogate_batch(archs)
-            elapsed = time.perf_counter() - t0
-            stage2_time += elapsed
-            n_batches += 1
-            total_cands += len(archs)
-        off = 0
-        for ctx in members:
-            ctx.surrogates = srs[off:off + len(ctx.active)]
-            # apportion the batched call's cost by candidate share
-            ctx.stage2_time_s = elapsed * len(ctx.active) / max(len(archs), 1)
-            ctx.stage2_candidates = len(ctx.active)
-            off += len(ctx.active)
-
-    # ---- generational lockstep for searching scenarios: each round, every
-    # active engine's pending population rides its group's one batched call
-    sgroups: Dict[str, List[_Ctx]] = {}
-    sorder: List[str] = []
-    for i, ctx in enumerate(ctxs):
-        if ctx.driver is None:
-            continue
-        key = (ctx.group_key if ctx.group_key is not None
-               else f"solo-{i}") + "|search"
-        if key not in sgroups:
-            sgroups[key] = []
-            sorder.append(key)
-        sgroups[key].append(ctx)
-    while any(not ctx.driver.done for key in sorder for ctx in sgroups[key]):
-        for key in sorder:
-            members = [ctx for ctx in sgroups[key] if not ctx.driver.done]
-            if not members:
+        # ---- stage 1 per scenario (search drivers do their own static pruning)
+        for ctx in ctxs:
+            if ctx.driver is not None:
                 continue
-            asks = [ctx.driver.ask_candidates() for ctx in members]
-            cands = [c for a in asks for c in a]
+            with span("spac.stage1") as s1:
+                ctx.active, ctx.log1 = stage1_static(
+                    ctx.problem, delta=ctx.scenario.fidelity.delta)
+            ctx.stage1_time_s = s1.seconds
+            if verbose:
+                print(f"[{ctx.scenario.name}] {ctx.log1}")
+
+        # ---- stage 2: fan every scenario's survivors through the batched engine;
+        # scenarios sharing (trace, bound, fidelity) share one call
+        groups: Dict[str, List[_Ctx]] = {}
+        order: List[str] = []
+        for i, ctx in enumerate(ctxs):
+            if ctx.driver is not None:
+                continue
+            key = ctx.group_key if ctx.group_key is not None else f"solo-{i}"
+            if key not in groups:
+                groups[key] = []
+                order.append(key)
+            groups[key].append(ctx)
+
+        total_cands = 0
+        stage2_time = 0.0
+        n_batches = 0
+        for key in order:
+            members = groups[key]
+            archs = [a for ctx in members for a in ctx.active]
+            srs: List[SurrogateResult] = []
             elapsed = 0.0
-            srs = []
-            if cands:
-                t0 = time.perf_counter()
-                srs = members[0].problem.surrogate_batch(cands)
-                elapsed = time.perf_counter() - t0
+            if archs:
+                with span("spac.stage2") as s2:
+                    srs = members[0].problem.surrogate_batch(archs)
+                elapsed = s2.seconds
                 stage2_time += elapsed
                 n_batches += 1
-                total_cands += len(cands)
+                total_cands += len(archs)
             off = 0
-            for ctx, a in zip(members, asks):
-                ctx.driver.tell_candidates(srs[off:off + len(a)])
-                ctx.stage2_time_s += elapsed * len(a) / max(len(cands), 1)
-                ctx.stage2_candidates += len(a)
-                off += len(a)
+            for ctx in members:
+                ctx.surrogates = srs[off:off + len(ctx.active)]
+                # apportion the batched call's cost by candidate share
+                ctx.stage2_time_s = elapsed * len(ctx.active) / max(len(archs), 1)
+                ctx.stage2_candidates = len(ctx.active)
+                off += len(ctx.active)
 
-    # ---- stage-2 screening (or search finalize) + stage-3 sizing
-    for ctx in ctxs:
-        s = ctx.scenario
-        t0 = time.perf_counter()
-        if ctx.driver is not None:
-            outcome = ctx.driver.finalize()
-            valid, ctx.log2 = outcome.valid, outcome.log
-            # match solo run_scenario accounting: finalize()'s archive
-            # re-surrogation (resume path) counts as stage-2 fan-out
-            ctx.stage2_candidates = outcome.surrogate_rows
-        else:
-            valid, ctx.log2 = stage2_screen(ctx.problem, ctx.active, s.sla,
-                                            surrogates=ctx.surrogates)
-        ctx.sized, ctx.n_explored = stage3_size(
-            ctx.problem, valid, s.sla, ctx.budget, top_k=s.fidelity.top_k)
-        ctx.stage3_time_s = time.perf_counter() - t0
-        if verbose:
-            print(f"[{s.name}] {ctx.log2}")
+        # ---- generational lockstep for searching scenarios: each round, every
+        # active engine's pending population rides its group's one batched call
+        sgroups: Dict[str, List[_Ctx]] = {}
+        sorder: List[str] = []
+        for i, ctx in enumerate(ctxs):
+            if ctx.driver is None:
+                continue
+            key = (ctx.group_key if ctx.group_key is not None
+                   else f"solo-{i}") + "|search"
+            if key not in sgroups:
+                sgroups[key] = []
+                sorder.append(key)
+            sgroups[key].append(ctx)
+        while any(not ctx.driver.done for key in sorder for ctx in sgroups[key]):
+            for key in sorder:
+                members = [ctx for ctx in sgroups[key] if not ctx.driver.done]
+                if not members:
+                    continue
+                asks = [ctx.driver.ask_candidates() for ctx in members]
+                cands = [c for a in asks for c in a]
+                elapsed = 0.0
+                srs = []
+                if cands:
+                    with span("spac.stage2") as s2:
+                        srs = members[0].problem.surrogate_batch(cands)
+                    elapsed = s2.seconds
+                    stage2_time += elapsed
+                    n_batches += 1
+                    total_cands += len(cands)
+                off = 0
+                for ctx, a in zip(members, asks):
+                    ctx.driver.tell_candidates(srs[off:off + len(a)])
+                    ctx.stage2_time_s += elapsed * len(a) / max(len(cands), 1)
+                    ctx.stage2_candidates += len(a)
+                    off += len(a)
 
-    # ---- stage 4: fan every scenario's sized survivors through the batched
-    # verifier; scenarios sharing (trace, bound, fidelity, engine) share one
-    # jitted call, exactly as stage 2 shares the surrogate scan
-    vgroups: Dict[str, List[_Ctx]] = {}
-    vorder: List[str] = []
-    for i, ctx in enumerate(ctxs):
-        key = _verify_group_key(ctx) or f"solo-{i}"
-        if key not in vgroups:
-            vgroups[key] = []
-            vorder.append(key)
-        vgroups[key].append(ctx)
+        # ---- stage-2 screening (or search finalize) + stage-3 sizing
+        for ctx in ctxs:
+            s = ctx.scenario
+            with span("spac.screen") as sc:
+                if ctx.driver is not None:
+                    outcome = ctx.driver.finalize()
+                    valid, ctx.log2 = outcome.valid, outcome.log
+                    # match solo run_scenario accounting: finalize()'s archive
+                    # re-surrogation (resume path) counts as stage-2 fan-out
+                    ctx.stage2_candidates = outcome.surrogate_rows
+                else:
+                    valid, ctx.log2 = stage2_screen(
+                        ctx.problem, ctx.active, s.sla,
+                        surrogates=ctx.surrogates)
+            with span("spac.stage3") as s3:
+                ctx.sized, ctx.n_explored = stage3_size(
+                    ctx.problem, valid, s.sla, ctx.budget,
+                    top_k=s.fidelity.top_k)
+            ctx.stage3_time_s = sc.seconds + s3.seconds
+            if verbose:
+                print(f"[{s.name}] {ctx.log2}")
 
-    total_verifies = 0
-    stage4_time = 0.0
-    n_vbatches = 0
-    for key in vorder:
-        members = vgroups[key]
-        cands = [a for ctx in members for a, _ in ctx.sized]
-        vs: List[VerifyResult] = []
-        elapsed = 0.0
-        if cands:
-            t0 = time.perf_counter()
-            vs = members[0].problem.verify_batch(cands)
-            elapsed = time.perf_counter() - t0
-            stage4_time += elapsed
-            n_vbatches += 1
-            total_verifies += len(cands)
-        off = 0
-        for ctx in members:
-            ctx.verifies = vs[off:off + len(ctx.sized)]
-            # apportion the batched call's cost by candidate share
-            ctx.stage4_time_s = elapsed * len(ctx.sized) / max(len(cands), 1)
-            off += len(ctx.sized)
+        # ---- stage 4: fan every scenario's sized survivors through the batched
+        # verifier; scenarios sharing (trace, bound, fidelity, engine) share one
+        # jitted call, exactly as stage 2 shares the surrogate scan
+        vgroups: Dict[str, List[_Ctx]] = {}
+        vorder: List[str] = []
+        for i, ctx in enumerate(ctxs):
+            key = _verify_group_key(ctx) or f"solo-{i}"
+            if key not in vgroups:
+                vgroups[key] = []
+                vorder.append(key)
+            vgroups[key].append(ctx)
 
-    # ---- assemble per-scenario results
-    reports: List[ScenarioReport] = []
-    for ctx in ctxs:
-        s = ctx.scenario
-        t0 = time.perf_counter()
-        evaluated, best, best_v = stage4_verify(ctx.problem, ctx.sized, s.sla,
-                                                verifies=ctx.verifies)
-        log3 = StageLog("stage3-sizing+verify", ctx.n_explored, len(ctx.sized))
-        result = finalize_result(
-            ctx.problem, evaluated, best, best_v,
-            [lg for lg in (ctx.log1, ctx.log2, log3) if lg is not None])
-        if verbose:
-            print(f"[{s.name}] {log3}")
-        reports.append(ScenarioReport(
-            scenario=s, result=result, problem=ctx.problem,
-            wall_time_s=(ctx.stage1_time_s + ctx.stage2_time_s
-                         + ctx.stage3_time_s + ctx.stage4_time_s
-                         + time.perf_counter() - t0),
-            stage2_candidates=ctx.stage2_candidates,
-            stage2_time_s=ctx.stage2_time_s,
-            stage4_candidates=len(ctx.sized),
-            stage4_time_s=ctx.stage4_time_s))
+        total_verifies = 0
+        stage4_time = 0.0
+        n_vbatches = 0
+        for key in vorder:
+            members = vgroups[key]
+            cands = [a for ctx in members for a, _ in ctx.sized]
+            vs: List[VerifyResult] = []
+            elapsed = 0.0
+            if cands:
+                with span("spac.stage4") as s4:
+                    vs = members[0].problem.verify_batch(cands)
+                elapsed = s4.seconds
+                stage4_time += elapsed
+                n_vbatches += 1
+                total_verifies += len(cands)
+            off = 0
+            for ctx in members:
+                ctx.verifies = vs[off:off + len(ctx.sized)]
+                # apportion the batched call's cost by candidate share
+                ctx.stage4_time_s = elapsed * len(ctx.sized) / max(len(cands), 1)
+                off += len(ctx.sized)
 
-    return CampaignReport(
-        name=name,
-        reports=reports,
-        stage2_candidates=total_cands,
-        stage2_time_s=stage2_time,
-        stage2_batches=n_batches,
-        stage4_candidates=total_verifies,
-        stage4_time_s=stage4_time,
-        stage4_batches=n_vbatches,
-        shared_trace_scenarios=sum(c.shared_trace for c in ctxs),
-        wall_time_s=time.perf_counter() - t_start,
-    )
+        # ---- assemble per-scenario results
+        reports: List[ScenarioReport] = []
+        for ctx in ctxs:
+            s = ctx.scenario
+            with span("spac.finalize") as fin:
+                evaluated, best, best_v = stage4_verify(
+                    ctx.problem, ctx.sized, s.sla, verifies=ctx.verifies)
+                log3 = StageLog("stage3-sizing+verify", ctx.n_explored,
+                                len(ctx.sized))
+                result = finalize_result(
+                    ctx.problem, evaluated, best, best_v,
+                    [lg for lg in (ctx.log1, ctx.log2, log3) if lg is not None])
+            if verbose:
+                print(f"[{s.name}] {log3}")
+            reports.append(ScenarioReport(
+                scenario=s, result=result, problem=ctx.problem,
+                wall_time_s=(ctx.stage1_time_s + ctx.stage2_time_s
+                             + ctx.stage3_time_s + ctx.stage4_time_s
+                             + fin.seconds),
+                stage2_candidates=ctx.stage2_candidates,
+                stage2_time_s=ctx.stage2_time_s,
+                stage4_candidates=len(ctx.sized),
+                stage4_time_s=ctx.stage4_time_s))
+
+        return CampaignReport(
+            name=name,
+            reports=reports,
+            stage2_candidates=total_cands,
+            stage2_time_s=stage2_time,
+            stage2_batches=n_batches,
+            stage4_candidates=total_verifies,
+            stage4_time_s=stage4_time,
+            stage4_batches=n_vbatches,
+            shared_trace_scenarios=sum(c.shared_trace for c in ctxs),
+            wall_time_s=root.seconds,
+        )

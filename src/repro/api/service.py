@@ -45,6 +45,7 @@ import json
 import time
 from typing import Any, Dict, List, Mapping, Optional, Tuple, Union
 
+from repro.analysis.spans import span
 from repro.core.dse import IncrementalDSE
 from repro.serve.slots import SlotArray
 
@@ -127,6 +128,7 @@ class ServeRequest:
     problem: Any = None
     stage2_time_s: float = 0.0               # this request's share of chunks
     stage4_time_s: float = 0.0
+    root: Optional[span] = None              # spac.serve.request, submit..finish
 
     @property
     def done(self) -> bool:
@@ -206,6 +208,7 @@ class DSEServeEngine:
         req = ServeRequest(rid=rid, scenario=scenario,
                            key=request_key(scenario),
                            submit_time_s=time.perf_counter())
+        req.root = span("spac.serve.request", detached=True, rid=rid).begin()
         self._slots.submit(rid, req)
         self.counters["requests"] += 1
         return req
@@ -269,6 +272,11 @@ class DSEServeEngine:
         chunk per (problem, fidelity) group, retire finished requests.
         Returns the number of occupied slots after the tick."""
         self._ticks += 1
+        with span("spac.serve.tick", tick=self._ticks):
+            self._tick()
+        return len(self._slots)
+
+    def _tick(self) -> None:
         # keys some active request is already computing: a twin admitted
         # while its key is in flight waits in its slot (machine None) and is
         # served from the report cache when the original finishes, so
@@ -277,6 +285,7 @@ class DSEServeEngine:
                     if r.machine is not None}
         for slot, _, req in self._slots.admit():
             req.admit_time_s = time.perf_counter()
+            req.root.note(queued_s=req.admit_time_s - req.submit_time_s)
             if self._try_cached(slot, req):
                 continue
             if req.key in inflight:
@@ -318,7 +327,11 @@ class DSEServeEngine:
             if req.key not in still and self._try_start(slot, req):
                 self.counters["report_misses"] += 1
                 still.add(req.key)
-        return len(self._slots)
+
+    def _retire(self, slot: int, req: ServeRequest) -> None:
+        req.finish_time_s = time.perf_counter()
+        req.root.end()
+        self._slots.finish(slot)
 
     def _try_cached(self, slot: int, req: ServeRequest) -> bool:
         hit = self._reports.get(req.key)
@@ -327,8 +340,7 @@ class DSEServeEngine:
         self.counters["report_hits"] += 1
         req.report = json.loads(json.dumps(hit))
         req.cached = True
-        req.finish_time_s = time.perf_counter()
-        self._slots.finish(slot)
+        self._retire(slot, req)
         return True
 
     def _try_start(self, slot: int, req: ServeRequest) -> bool:
@@ -337,9 +349,8 @@ class DSEServeEngine:
             return True
         except Exception as e:  # noqa: BLE001 — a bad spec must not kill the service
             req.error = f"{type(e).__name__}: {e}"
-            req.finish_time_s = time.perf_counter()
             self.counters["errors"] += 1
-            self._slots.finish(slot)
+            self._retire(slot, req)
             return False
 
     def _run_chunk(self, kind: str, members: List[ServeRequest]) -> None:
@@ -357,12 +368,13 @@ class DSEServeEngine:
             return
         pad = width - len(take)
         chunk = take + [take[-1]] * pad
-        t0 = time.perf_counter()
-        if kind == "surrogate":
-            results = problem.surrogate_batch(chunk)
-        else:
-            results = problem.verify_batch(chunk)
-        elapsed = time.perf_counter() - t0
+        with span("spac.serve.chunk", kind=kind, pad_rows=pad,
+                  ids=[m.rid for m, n in zip(members, shares) if n]) as ch:
+            if kind == "surrogate":
+                results = problem.surrogate_batch(chunk)
+            else:
+                results = problem.verify_batch(chunk)
+        elapsed = ch.seconds
         results = list(results)[:len(take)]
         off = 0
         for req, n in zip(members, shares):
@@ -386,21 +398,21 @@ class DSEServeEngine:
             self.counters["stage4_chunks"] += 1
 
     def _finalize(self, slot: int, req: ServeRequest) -> None:
-        m = req.machine
-        report = ScenarioReport(
-            scenario=req.scenario, result=m.result, problem=req.problem,
-            wall_time_s=time.perf_counter() - req.admit_time_s,
-            stage2_candidates=m.stage2_candidates,
-            stage2_time_s=req.stage2_time_s,
-            stage4_candidates=m.stage4_candidates,
-            stage4_time_s=req.stage4_time_s)
-        d = report.to_dict()
-        self._reports[req.key] = d
-        _evict(self._reports, _MAX_REPORTS)
-        req.report = json.loads(json.dumps(d))
-        req.finish_time_s = time.perf_counter()
-        req.machine = None                     # free the stage state
-        self._slots.finish(slot)
+        with span("spac.serve.finalize", rid=req.rid):
+            m = req.machine
+            report = ScenarioReport(
+                scenario=req.scenario, result=m.result, problem=req.problem,
+                wall_time_s=time.perf_counter() - req.admit_time_s,
+                stage2_candidates=m.stage2_candidates,
+                stage2_time_s=req.stage2_time_s,
+                stage4_candidates=m.stage4_candidates,
+                stage4_time_s=req.stage4_time_s)
+            d = report.to_dict()
+            self._reports[req.key] = d
+            _evict(self._reports, _MAX_REPORTS)
+            req.report = json.loads(json.dumps(d))
+            req.machine = None                 # free the stage state
+            self._retire(slot, req)
 
     # -------------------------------------------------------------- driving
     def run_until_drained(self, max_ticks: int = 100_000) -> List[ServeRequest]:

@@ -24,6 +24,8 @@ from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.analysis.spans import note, span
+
 from .pareto import pareto_front
 
 if TYPE_CHECKING:                      # import cycle: search imports this module
@@ -273,12 +275,14 @@ def depth_for_drop_rate(q_occupancy: np.ndarray, eps: float) -> int:
 
 def stage1_static(problem: DSEProblem, *, delta: float = 0.2) -> Tuple[List[Any], StageLog]:
     """Stage 1: static timing pruning over the enumerated templates."""
-    cands = list(problem.candidates())
-    active = []
-    for a in cands:
-        t_proc, t_arrival = problem.static_timing(a)
-        if t_proc <= (1.0 + delta) * t_arrival:
-            active.append(a)
+    with span("spac.stage1"):
+        cands = list(problem.candidates())
+        active = []
+        for a in cands:
+            t_proc, t_arrival = problem.static_timing(a)
+            if t_proc <= (1.0 + delta) * t_arrival:
+                active.append(a)
+        note(survivors=len(active))
     return active, StageLog("stage1-static", len(cands), len(active))
 
 
@@ -299,11 +303,13 @@ def stage2_screen(
     """
     active = list(active)
     srs = list(surrogates) if surrogates is not None else problem.surrogate_batch(active)
-    check_index_aligned(problem, srs, active, "surrogate_batch")
-    valid: List[Tuple[Any, SurrogateResult]] = []
-    for a, sr in zip(active, srs):
-        if sr.p(99) <= sla.p99_latency_ns and sr.throughput_gbps >= sla.min_throughput_gbps:
-            valid.append((a, sr))
+    with span("spac.screen"):
+        check_index_aligned(problem, srs, active, "surrogate_batch")
+        valid: List[Tuple[Any, SurrogateResult]] = []
+        for a, sr in zip(active, srs):
+            if sr.p(99) <= sla.p99_latency_ns and sr.throughput_gbps >= sla.min_throughput_gbps:
+                valid.append((a, sr))
+        note(survivors=len(valid))
     return valid, StageLog("stage2-surrogate", len(active), len(valid))
 
 
@@ -323,6 +329,13 @@ def stage3_size(
     the budget come back as index-stable ``(sized, resources)`` pairs so the
     whole batch can fan through one ``verify_batch`` call.
     """
+    with span("spac.stage3"):
+        sized, n_explored = _size(problem, valid, sla, budget, top_k)
+        note(survivors=len(sized))
+    return sized, n_explored
+
+
+def _size(problem, valid, sla, budget, top_k):
     valid = sorted(valid, key=lambda av: av[1].p(99))
     explored = list(valid[: top_k if top_k > 0 else len(valid)])
     seen_keys = {id(a) for a, _ in explored}

@@ -43,6 +43,8 @@ from typing import (Any, Dict, Iterator, List, Mapping, Optional, Sequence,
 
 import numpy as np
 
+from repro.analysis.spans import note, span
+
 from .dse import SLA, StageLog, SurrogateResult, check_index_aligned
 from .pareto import hypervolume_2d, pareto_front
 
@@ -628,23 +630,29 @@ class SearchDriver:
     # ------------------------------------------------------------ ask/tell
     def ask_candidates(self) -> List[Any]:
         """Unique, static-feasible, un-cached candidates of this generation."""
-        genomes = self.engine.ask()
-        self._pending_genomes = genomes
-        cands: List[Any] = []
-        seen: set = set()
-        for g in genomes:
-            c = self._decode(g)
-            if not self._static_ok[g]:
-                continue                       # told as infeasible, no eval
-            if c in self._sr or c in seen:
-                continue                       # phenotype cache hit
-            seen.add(c)
-            cands.append(c)
-        self._pending_cands = cands
+        with span("spac.search.ask", generation=self.engine.generation):
+            genomes = self.engine.ask()
+            self._pending_genomes = genomes
+            cands: List[Any] = []
+            seen: set = set()
+            for g in genomes:
+                c = self._decode(g)
+                if not self._static_ok[g]:
+                    continue                   # told as infeasible, no eval
+                if c in self._sr or c in seen:
+                    continue                   # phenotype cache hit
+                seen.add(c)
+                cands.append(c)
+            self._pending_cands = cands
+            note(rows=len(cands))
         return list(cands)
 
     def tell_candidates(self, results: Sequence[SurrogateResult]) -> None:
         """Map batched surrogate results back to genomes; advance one gen."""
+        with span("spac.search.tell", generation=self.engine.generation):
+            self._tell(results)
+
+    def _tell(self, results: Sequence[SurrogateResult]) -> None:
         check_index_aligned(self.problem, results, self._pending_cands,
                             "surrogate_batch")
         for c, sr in zip(self._pending_cands, results):
@@ -737,17 +745,19 @@ def run_search(problem, spec: SearchSpec, sla: SLA, *, delta: float = 0.2,
     interrupted long campaign: the state is on disk, and a later call with
     ``resume=True`` continues bit-identically where this one stopped.
     """
-    driver = SearchDriver(problem, spec, sla, delta=delta,
-                          checkpoint_dir=checkpoint_dir, resume=resume)
-    start_gen = driver.engine.generation
-    while not driver.done:
-        if (max_generations_this_run is not None
-                and driver.engine.generation - start_gen >= max_generations_this_run):
-            break
-        cands = driver.ask_candidates()
-        srs = problem.surrogate_batch(cands)
-        driver.tell_candidates(srs)
-    return driver.finalize()
+    with span("spac.search"):
+        driver = SearchDriver(problem, spec, sla, delta=delta,
+                              checkpoint_dir=checkpoint_dir, resume=resume)
+        start_gen = driver.engine.generation
+        while not driver.done:
+            if (max_generations_this_run is not None
+                    and driver.engine.generation - start_gen
+                    >= max_generations_this_run):
+                break
+            cands = driver.ask_candidates()
+            srs = problem.surrogate_batch(cands)
+            driver.tell_candidates(srs)
+        return driver.finalize()
 
 
 # --------------------------------------------------------------------------

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.retrace import track
+from repro.analysis.spans import span
 
 from .kernel import netsim_replay_padded
 from .ref import netsim_replay_abs_ref
@@ -380,29 +381,31 @@ def netsim_fixed_point(
     k = 1 if mesh_spec is None else mesh_spec.shard_axis
     depth32 = np.minimum(depth, np.int64(2**31 - 1)).astype(np.int32)
 
-    now_j = jnp.asarray(now)
-    src_j = jnp.asarray(src, jnp.int32)
-    dst_j = jnp.asarray(dst, jnp.int32)
-    perm_j = jnp.asarray(chain.perm, jnp.int32)
-    seg_j = jnp.asarray(chain.seg_start, jnp.int32)
-    rank_j = jnp.asarray(chain.rank, jnp.int32)
-
-    if k > 1:
-        from repro.launch.mesh import shard_pad
-        fn = _sharded_round1(mesh_spec.build(), n_ports)
-        end, ok = fn(now_j, src_j, dst_j,
-                     jnp.asarray(shard_pad(svc, k).T),
-                     jnp.asarray(shard_pad(pipe, k)),
-                     jnp.asarray(shard_pad(depth32, k)),
-                     perm_j, seg_j, rank_j)
-    else:
-        end, ok = _round1(now_j, src_j, dst_j, jnp.asarray(svc.T),
-                          jnp.asarray(pipe), jnp.asarray(depth32),
-                          perm_j, seg_j, rank_j, n_ports=n_ports)
-    # np.array (not asarray): device output views are read-only and the
-    # subset iteration scatters into end below
-    end = np.array(end[:b_n])
-    ok = np.asarray(ok)[:b_n]
+    round1 = _sharded_round1(mesh_spec.build(), n_ports) if k > 1 else _round1
+    # the device call: copies in (the timeline stays for later rounds), the
+    # fused round, and the fetch back
+    with span("spac.stage4.round1", jit=round1):
+        now_j = jnp.asarray(now)
+        src_j = jnp.asarray(src, jnp.int32)
+        dst_j = jnp.asarray(dst, jnp.int32)
+        perm_j = jnp.asarray(chain.perm, jnp.int32)
+        seg_j = jnp.asarray(chain.seg_start, jnp.int32)
+        rank_j = jnp.asarray(chain.rank, jnp.int32)
+        if k > 1:
+            from repro.launch.mesh import shard_pad
+            end, ok = round1(now_j, src_j, dst_j,
+                             jnp.asarray(shard_pad(svc, k).T),
+                             jnp.asarray(shard_pad(pipe, k)),
+                             jnp.asarray(shard_pad(depth32, k)),
+                             perm_j, seg_j, rank_j)
+        else:
+            end, ok = round1(now_j, src_j, dst_j, jnp.asarray(svc.T),
+                             jnp.asarray(pipe), jnp.asarray(depth32),
+                             perm_j, seg_j, rank_j, n_ports=n_ports)
+        # np.array (not asarray): device output views are read-only and the
+        # subset iteration scatters into end below
+        end = np.array(end[:b_n])
+        ok = np.asarray(ok)[:b_n]
     admit = np.ones((b_n, m), bool)
     converged = ok.copy()
     if bool(ok.all()):
@@ -412,8 +415,11 @@ def netsim_fixed_point(
     sub_svc, sub_pipe = svc[rows], pipe[rows]
     sub_depth = depth32[rows]
     sub_end = end[rows]
-    cur = segmented_admission(sub_end, np.ones((rows.size, m), bool), now,
-                              sub_depth, chain)
+    with span("spac.stage4.admission", round=1):
+        cur = segmented_admission(sub_end, np.ones((rows.size, m), bool), now,
+                                  sub_depth, chain)
+    replay = (_sharded_gated_replay(mesh_spec.build(), n_ports) if k > 1
+              else _gated_replay)
     rounds = 1
     conv_sub = np.zeros(rows.size, bool)
     while rounds < max_rounds:
@@ -422,16 +428,18 @@ def netsim_fixed_point(
         svc_p = _pad_rows(sub_svc, size)
         admit_p = _pad_rows(cur, size)
         pipe_p = _pad_rows(sub_pipe, size)
-        if k > 1:
-            fn = _sharded_gated_replay(mesh_spec.build(), n_ports)
-            sub_end = np.asarray(fn(now_j, src_j, dst_j, jnp.asarray(svc_p),
-                                    jnp.asarray(pipe_p),
-                                    jnp.asarray(admit_p)))[:rows.size]
-        else:
-            sub_end = np.asarray(_gated_replay(
-                now_j, src_j, dst_j, jnp.asarray(svc_p), jnp.asarray(pipe_p),
-                jnp.asarray(admit_p), n_ports=n_ports))[:rows.size]
-        derived = segmented_admission(sub_end, cur, now, sub_depth, chain)
+        with span("spac.stage4.replay", jit=replay, round=rounds):
+            if k > 1:
+                sub_end = np.asarray(replay(
+                    now_j, src_j, dst_j, jnp.asarray(svc_p),
+                    jnp.asarray(pipe_p), jnp.asarray(admit_p)))[:rows.size]
+            else:
+                sub_end = np.asarray(replay(
+                    now_j, src_j, dst_j, jnp.asarray(svc_p),
+                    jnp.asarray(pipe_p), jnp.asarray(admit_p),
+                    n_ports=n_ports))[:rows.size]
+        with span("spac.stage4.admission", round=rounds):
+            derived = segmented_admission(sub_end, cur, now, sub_depth, chain)
         eq = (derived == cur).all(axis=1)
         conv_sub = np.asarray(eq)
         if bool(eq.all()):

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.retrace import track
+from repro.analysis.spans import note, span
 from repro.core.archspec import SwitchArch, VOQKind
 from repro.core.binding import BoundProtocol
 from repro.core.dse import VerifyResult
@@ -194,7 +195,9 @@ def _run_group(archs, bounds, trace, hw_list, cfg,
     size, so mixed-header co-design batches are partitioned by
     ``header_bytes`` upstream and each partition shares one timeline."""
     n = archs[0].n_ports
-    tl4 = stage4_timeline(trace, n, bounds[0].header_bytes, cfg.prop_delay_s)
+    with span("spac.stage4.timeline"):
+        tl4 = stage4_timeline(trace, n, bounds[0].header_bytes,
+                              cfg.prop_delay_s)
     t0 = tl4.t0
     m = t0.size
     wire = tl4.wire
@@ -206,9 +209,10 @@ def _run_group(archs, bounds, trace, hw_list, cfg,
     svc = np.empty((b_n, m), np.float64)
     pipe = np.empty(b_n, np.float64)
     depth = np.empty(b_n, np.int64)
-    for b, (arch, hw) in enumerate(zip(archs, hw_list)):
-        svc[b], pipe[b] = service_times(arch, hw, wire, link_bps)
-        depth[b] = arch.voq_depth
+    with span("spac.stage4.prepare"):
+        for b, (arch, hw) in enumerate(zip(archs, hw_list)):
+            svc[b], pipe[b] = service_times(arch, hw, wire, link_bps)
+            depth[b] = arch.voq_depth
 
     order = tl4.order                          # == the heap's (time, pkt) order
     now = tl4.now
@@ -221,79 +225,70 @@ def _run_group(archs, bounds, trace, hw_list, cfg,
     d_max = 1 << int(int(mod.max()) - 1).bit_length()
 
     k = 1 if mesh_spec is None else mesh_spec.shard_axis
-    if k > 1:
-        from repro.launch.mesh import shard_pad
-        svc_p = shard_pad(svc, k)
-        with jax.enable_x64():
-            end, admit = _sharded_verify_engine(mesh_spec.build(), n, d_max)(
-                jnp.asarray(now), jnp.asarray(tl4.src_o, jnp.int32),
-                jnp.asarray(tl4.dst_o, jnp.int32),
-                jnp.asarray(svc_p[:, order].T),
-                jnp.asarray(shard_pad(pipe, k)),
-                jnp.asarray(shard_pad(depth, k), jnp.int32),
-                jnp.asarray(shard_pad(mod, k)))
-    else:
-        with jax.enable_x64():
-            end, admit = _verify_engine(
-                jnp.asarray(now), jnp.asarray(tl4.src_o, jnp.int32),
-                jnp.asarray(tl4.dst_o, jnp.int32), jnp.asarray(svc[:, order].T),
-                jnp.asarray(pipe), jnp.asarray(depth, jnp.int32),
-                jnp.asarray(mod), n_ports=n, d_max=d_max)
-    end = np.asarray(end, np.float64)[:b_n]     # strip pad rows (no-op serial)
-    admit = np.asarray(admit, bool)[:b_n]
+    engine = (_sharded_verify_engine(mesh_spec.build(), n, d_max) if k > 1
+              else _verify_engine)
+    with span("spac.stage4.scan", jit=engine):
+        if k > 1:
+            from repro.launch.mesh import shard_pad
+            svc_p = shard_pad(svc, k)
+            with jax.enable_x64():
+                end, admit = engine(
+                    jnp.asarray(now), jnp.asarray(tl4.src_o, jnp.int32),
+                    jnp.asarray(tl4.dst_o, jnp.int32),
+                    jnp.asarray(svc_p[:, order].T),
+                    jnp.asarray(shard_pad(pipe, k)),
+                    jnp.asarray(shard_pad(depth, k), jnp.int32),
+                    jnp.asarray(shard_pad(mod, k)))
+        else:
+            with jax.enable_x64():
+                end, admit = engine(
+                    jnp.asarray(now), jnp.asarray(tl4.src_o, jnp.int32),
+                    jnp.asarray(tl4.dst_o, jnp.int32),
+                    jnp.asarray(svc[:, order].T),
+                    jnp.asarray(pipe), jnp.asarray(depth, jnp.int32),
+                    jnp.asarray(mod), n_ports=n, d_max=d_max)
+        end = np.asarray(end, np.float64)[:b_n]  # strip pad rows (no-op serial)
+        admit = np.asarray(admit, bool)[:b_n]
 
-    t0_min = tl4.t0_min
-    wire_e = tl4.wire_e
-    # one batched sort replaces the per-candidate np.sort the shared-cap
-    # check used to run inside the loop below
-    sorted_ends = _sorted_admitted_ends(
-        end, admit,
-        [b for b in range(b_n)
-         if archs[b].voq is VOQKind.SHARED and int(depth[b]) >= 1])
-    out: List[VerifyResult] = []
-    for b, (arch, bound, hw) in enumerate(zip(archs, bounds, hw_list)):
-        fallback = None
-        if int(depth[b]) < 1:
-            # degenerate depth<=0: serial semantics drop every packet; the
-            # scan's ring check can't express an always-full queue
-            fallback = "degenerate_depth"
-        elif arch.voq is VOQKind.SHARED and not _shared_cap_ok(
-                admit[b], sorted_ends[b], now, n * int(depth[b])):
-            # the global cap binds for this candidate: the per-queue-only scan
-            # diverges
-            fallback = "shared_cap"
-        if fallback is not None:
-            # replay through the exact serial oracle, flagged for honesty
-            v = run_netsim(arch, bound, trace, hw=hw, cfg=cfg)
-            v.meta["shared_cap_fallback"] = fallback == "shared_cap"
-            v.meta["fallback"] = fallback
-            out.append(v)
-            continue
-        # reconstruct the serial path's packet-ordered latency array exactly
-        latency = np.full(m, np.nan)
-        latency[order] = np.where(
-            admit[b], (end[b] + cfg.prop_delay_s - t0[order]) * 1e9, np.nan)
-        done = ~np.isnan(latency)
-        lat = latency[done]
-        t_end = float(np.max(end[b], where=admit[b], initial=0.0))
-        delivered_bits = float(int(wire_e[admit[b]].sum()) * 8)
-        duration = max(t_end - t0_min, 1e-12)
-        out.append(VerifyResult(
-            p99_latency_ns=float(np.percentile(lat, 99)) if lat.size else math.inf,
-            mean_latency_ns=float(lat.mean()) if lat.size else math.inf,
-            drop_rate=int((~admit[b]).sum()) / max(m, 1),
-            throughput_gbps=delivered_bits / duration / 1e9,
-            meta={"latency_ns": lat, "latency_full_ns": latency,
-                  "delivered": int(done.sum()),
-                  "offered": int(m), "hw": hw, "engine": "batched_netsim"},
-        ))
+    with span("spac.stage4.reduce"):
+        # one batched sort replaces the per-candidate np.sort the shared-cap
+        # check used to run inside the loop below
+        sorted_ends = _sorted_admitted_ends(
+            end, admit,
+            [b for b in range(b_n)
+             if archs[b].voq is VOQKind.SHARED and int(depth[b]) >= 1])
+        out: List[VerifyResult] = []
+        n_fall = 0
+        for b, (arch, bound, hw) in enumerate(zip(archs, bounds, hw_list)):
+            fallback = None
+            if int(depth[b]) < 1:
+                # degenerate depth<=0: serial semantics drop every packet; the
+                # scan's ring check can't express an always-full queue
+                fallback = "degenerate_depth"
+            elif arch.voq is VOQKind.SHARED and not _shared_cap_ok(
+                    admit[b], sorted_ends[b], now, n * int(depth[b])):
+                # the global cap binds for this candidate: the per-queue-only
+                # scan diverges
+                fallback = "shared_cap"
+            if fallback is not None:
+                # replay through the exact serial oracle, flagged for honesty
+                n_fall += 1
+                with span("spac.stage4.fallback", reason=fallback):
+                    v = run_netsim(arch, bound, trace, hw=hw, cfg=cfg)
+                v.meta["shared_cap_fallback"] = fallback == "shared_cap"
+                v.meta["fallback"] = fallback
+                out.append(v)
+                continue
+            out.append(_metrics_result(end[b], admit[b], order, t0,
+                                       tl4.wire_e, tl4.t0_min, cfg, hw, m))
+    note(fallback_rows=n_fall)
     return out
 
 
 def _metrics_result(end_b, admit_b, order, t0, wire_e, t0_min, cfg, hw,
                     m) -> VerifyResult:
-    """Reduce one candidate's (end, admit) to a VerifyResult — verbatim the
-    default path's reduction, so kernel-path results are bit-identical."""
+    """Reduce one candidate's (end, admit) to a VerifyResult; both paths
+    reduce through it, so kernel-path results are bit-identical."""
     latency = np.full(m, np.nan)
     latency[order] = np.where(
         admit_b, (end_b + cfg.prop_delay_s - t0[order]) * 1e9, np.nan)
@@ -329,7 +324,9 @@ def _run_group_kernel(archs, bounds, trace, hw_list, cfg,
     take the serial oracle, flagged in ``meta`` exactly like the default
     path's fallbacks."""
     n = archs[0].n_ports
-    tl4 = stage4_timeline(trace, n, bounds[0].header_bytes, cfg.prop_delay_s)
+    with span("spac.stage4.timeline"):
+        tl4 = stage4_timeline(trace, n, bounds[0].header_bytes,
+                              cfg.prop_delay_s)
     m = tl4.now.size
     b_n = len(archs)
     if m == 0:
@@ -340,11 +337,6 @@ def _run_group_kernel(archs, bounds, trace, hw_list, cfg,
     svc_e = np.empty((b_n, m), np.float64)      # event order (pre-permuted)
     pipe = np.empty(b_n, np.float64)
     depth = np.empty(b_n, np.int64)
-    for b, (arch, hw) in enumerate(zip(archs, hw_list)):
-        s, pipe[b] = service_times(arch, hw, tl4.wire, link_bps)
-        svc_e[b] = s[order]
-        depth[b] = arch.voq_depth
-
     out: List[Optional[VerifyResult]] = [None] * b_n
     fall: Dict[int, str] = {}
     # candidate dedup: rows with identical (service times, pipe, depth, VOQ
@@ -352,43 +344,51 @@ def _run_group_kernel(archs, bounds, trace, hw_list, cfg,
     slot_of: Dict[Tuple, int] = {}
     uniq_rows: List[int] = []
     rep = np.full(b_n, -1, np.int64)
-    for b in range(b_n):
-        if int(depth[b]) < 1:
-            fall[b] = "degenerate_depth"
-            continue
-        key = (svc_e[b].tobytes(), float(pipe[b]), int(depth[b]),
-               archs[b].voq is VOQKind.SHARED)
-        slot = slot_of.setdefault(key, len(uniq_rows))
-        if slot == len(uniq_rows):
-            uniq_rows.append(b)
-        rep[b] = slot
+    with span("spac.stage4.prepare"):
+        for b, (arch, hw) in enumerate(zip(archs, hw_list)):
+            s, pipe[b] = service_times(arch, hw, tl4.wire, link_bps)
+            svc_e[b] = s[order]
+            depth[b] = arch.voq_depth
+        for b in range(b_n):
+            if int(depth[b]) < 1:
+                fall[b] = "degenerate_depth"
+                continue
+            key = (svc_e[b].tobytes(), float(pipe[b]), int(depth[b]),
+                   archs[b].voq is VOQKind.SHARED)
+            slot = slot_of.setdefault(key, len(uniq_rows))
+            if slot == len(uniq_rows):
+                uniq_rows.append(b)
+            rep[b] = slot
 
     uniq_res: List[Optional[VerifyResult]] = []
+    rounds = 0
     if uniq_rows:
         ui = np.asarray(uniq_rows)
         with jax.enable_x64():
-            end, admit, conv, _rounds = netsim_fixed_point(
+            end, admit, conv, rounds = netsim_fixed_point(
                 now, tl4.src_o.astype(np.int32), tl4.dst_o.astype(np.int32),
                 svc_e[ui], pipe[ui], depth[ui], n_ports=n, chain=tl4.chain,
                 mesh_spec=mesh_spec)
-        sorted_ends = _sorted_admitted_ends(
-            end, admit,
-            [i for i, b in enumerate(uniq_rows)
-             if archs[b].voq is VOQKind.SHARED and bool(conv[i])])
-        for i, b in enumerate(uniq_rows):
-            if not bool(conv[i]):
-                uniq_res.append(None)
-                fall[b] = "kernel_unconverged"
-                continue
-            if archs[b].voq is VOQKind.SHARED and not _shared_cap_ok(
-                    admit[i], sorted_ends[i], now, n * int(depth[b])):
-                uniq_res.append(None)
-                fall[b] = "shared_cap"
-                continue
-            uniq_res.append(_metrics_result(
-                end[i], admit[i], order, t0, tl4.wire_e, tl4.t0_min, cfg,
-                hw_list[b], m))
+        with span("spac.stage4.reduce"):
+            sorted_ends = _sorted_admitted_ends(
+                end, admit,
+                [i for i, b in enumerate(uniq_rows)
+                 if archs[b].voq is VOQKind.SHARED and bool(conv[i])])
+            for i, b in enumerate(uniq_rows):
+                if not bool(conv[i]):
+                    uniq_res.append(None)
+                    fall[b] = "kernel_unconverged"
+                    continue
+                if archs[b].voq is VOQKind.SHARED and not _shared_cap_ok(
+                        admit[i], sorted_ends[i], now, n * int(depth[b])):
+                    uniq_res.append(None)
+                    fall[b] = "shared_cap"
+                    continue
+                uniq_res.append(_metrics_result(
+                    end[i], admit[i], order, t0, tl4.wire_e, tl4.t0_min, cfg,
+                    hw_list[b], m))
 
+    n_fall = 0
     for b in range(b_n):
         slot = int(rep[b])
         if slot >= 0 and uniq_res[slot] is not None:
@@ -401,10 +401,14 @@ def _run_group_kernel(archs, bounds, trace, hw_list, cfg,
             # the fixed point defers to the serial oracle, flagged exactly
             # like the default path
             fb = fall.get(b) or fall.get(uniq_rows[slot], "kernel_unconverged")
-            v = run_netsim(archs[b], bounds[b], trace, hw=hw_list[b], cfg=cfg)
+            n_fall += 1
+            with span("spac.stage4.fallback", reason=fb):
+                v = run_netsim(archs[b], bounds[b], trace, hw=hw_list[b],
+                               cfg=cfg)
             v.meta["shared_cap_fallback"] = fb == "shared_cap"
             v.meta["fallback"] = fb
             out[b] = v
+    note(unique_rows=len(uniq_rows), rounds=rounds, fallback_rows=n_fall)
     return out
 
 

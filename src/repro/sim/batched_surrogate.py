@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.retrace import track
+from repro.analysis.spans import span
 from repro.core.archspec import SwitchArch, VOQKind
 from repro.core.binding import BoundProtocol
 from repro.core.dse import SurrogateResult
@@ -206,7 +207,8 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
     header widths still ride one jitted scan: the header only reshapes the
     per-candidate service times and delivered wire bits."""
     n = archs[0].n_ports
-    tl2 = stage2_timeline(trace, n)
+    with span("spac.stage2.timeline"):
+        tl2 = stage2_timeline(trace, n)
     t, src, dst, payload = tl2.t, tl2.src, tl2.dst, tl2.payload
     m = t.size
 
@@ -218,19 +220,20 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
     # one wire-size array per distinct header width: classic shared-bound
     # batches pay for it once, co-design pays once per layout width
     wire_cache: Dict[int, Any] = {}
-    for b, (arch, bound, hw) in enumerate(zip(archs, bounds, hw_list)):
-        cached = wire_cache.get(bound.header_bytes)
-        if cached is None:
-            wb = payload + bound.header_bytes
-            cached = (wb, float(wb.sum() * 8))
-            wire_cache[bound.header_bytes] = cached
-        wire_bytes, wire_bits[b] = cached
-        flit_bytes = arch.bus_bits // 8
-        size_flits = np.maximum(1, -(-wire_bytes // flit_bytes))
-        svc[b] = (size_flits + hw.ingress_stall_cycles) / (hw.fclk_hz * hw.eta)
-        pipe_s[b] = (hw.pipeline_cycles + hw.arb_cycles) / hw.fclk_hz
-        feasible[b] = bool(m == 0 or svc[b].mean() * hw.fclk_hz
-                           <= arch.ii * size_flits.mean() * 1.25)
+    with span("spac.stage2.prepare"):
+        for b, (arch, bound, hw) in enumerate(zip(archs, bounds, hw_list)):
+            cached = wire_cache.get(bound.header_bytes)
+            if cached is None:
+                wb = payload + bound.header_bytes
+                cached = (wb, float(wb.sum() * 8))
+                wire_cache[bound.header_bytes] = cached
+            wire_bytes, wire_bits[b] = cached
+            flit_bytes = arch.bus_bits // 8
+            size_flits = np.maximum(1, -(-wire_bytes // flit_bytes))
+            svc[b] = (size_flits + hw.ingress_stall_cycles) / (hw.fclk_hz * hw.eta)
+            pipe_s[b] = (hw.pipeline_cycles + hw.arb_cycles) / hw.fclk_hz
+            feasible[b] = bool(m == 0 or svc[b].mean() * hw.fclk_hz
+                               <= arch.ii * size_flits.mean() * 1.25)
 
     dtype = np.float64 if precision == "float64" else np.float32
     if m == 0:
@@ -248,43 +251,49 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
                     shard_pad(svc.astype(dtype), k),
                     t.astype(dtype),
                     shard_pad(wire_bits.astype(dtype), k))
-            engine = _sharded_engine(mesh_spec.build(), n, use_pallas,
-                                     interpret)
+            engine = jitted = _sharded_engine(mesh_spec.build(), n, use_pallas,
+                                              interpret)
         else:
             args = (dt.astype(dtype), src.astype(np.int32),
                     dst.astype(np.int32), svc.astype(dtype), t.astype(dtype),
                     wire_bits.astype(dtype))
+            jitted = _engine
             engine = functools.partial(_engine, n_ports=n,
                                        use_pallas=use_pallas,
                                        interpret=interpret)
-        if precision == "float64":
-            with jax.enable_x64():
+        # the device call: copies in, the scan, and the fetch back
+        with span("spac.stage2.scan", jit=jitted):
+            if precision == "float64":
+                with jax.enable_x64():
+                    dep, thru = engine(*args)
+                    dep, thru = np.asarray(dep), np.asarray(thru)
+            else:
                 dep, thru = engine(*args)
-                dep, thru = np.asarray(dep), np.asarray(thru)
-        else:
-            dep, thru = engine(*args)
-            dep, thru = np.asarray(dep, np.float64), np.asarray(thru, np.float64)
+                dep, thru = (np.asarray(dep, np.float64),
+                             np.asarray(thru, np.float64))
         dep, thru = dep[:b_n], thru[:b_n]       # strip pad rows (no-op serial)
-    if precision == "float64":
-        # the f64 scan returns absolute departure times so the occupancy
-        # comparisons below see the serial path's exact values (no offset
-        # round-trip); latency then subtracts t exactly as the serial model
-        dep_end = np.asarray(dep, np.float64)
-        lat = (dep_end - t[None, :] + pipe_s[:, None]) * 1e9
-    else:
-        dep_end = t[None, :] + np.asarray(dep, np.float64)
-        lat = (dep + pipe_s[:, None]) * 1e9
-    quant = (np.percentile(lat, quantiles, axis=1).T if m
-             else np.zeros((b_n, len(quantiles))))
-    if m == 0:
-        occupancy = np.zeros((b_n, 0), np.int64)
-    elif use_kernel:
-        # one flat searchsorted over the whole [B, m] block (chain structure
-        # from the trace memo) — integer counts bit-identical to the serial
-        # per-row reference, asserted in tests/test_netsim_kernels.py
-        occupancy = segmented_occupancy(np.asarray(t), dep_end, tl2.chain)
-    else:
-        occupancy = _exact_occupancy(t, tl2.qid, dep_end)
+    with span("spac.stage2.reduce"):
+        if precision == "float64":
+            # the f64 scan returns absolute departure times so the occupancy
+            # comparisons below see the serial path's exact values (no offset
+            # round-trip); latency then subtracts t exactly as the serial model
+            dep_end = np.asarray(dep, np.float64)
+            lat = (dep_end - t[None, :] + pipe_s[:, None]) * 1e9
+        else:
+            dep_end = t[None, :] + np.asarray(dep, np.float64)
+            lat = (dep + pipe_s[:, None]) * 1e9
+        quant = (np.percentile(lat, quantiles, axis=1).T if m
+                 else np.zeros((b_n, len(quantiles))))
+        if m == 0:
+            occupancy = np.zeros((b_n, 0), np.int64)
+        elif use_kernel:
+            # one flat searchsorted over the whole [B, m] block (chain
+            # structure from the trace memo) — integer counts bit-identical
+            # to the serial per-row reference, asserted in
+            # tests/test_netsim_kernels.py
+            occupancy = segmented_occupancy(np.asarray(t), dep_end, tl2.chain)
+        else:
+            occupancy = _exact_occupancy(t, tl2.qid, dep_end)
     return BatchedSurrogateResult(
         archs=list(archs), hw=list(hw_list),
         latency_ns=np.asarray(lat, np.float64),

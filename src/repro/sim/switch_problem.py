@@ -12,6 +12,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.analysis.spans import span
 from repro.core.archspec import (AUTO, ArchRequest, BUS_WIDTHS,
                                  ForwardTableKind, SchedulerKind, SwitchArch,
                                  VOQKind, enumerate_candidates)
@@ -349,12 +350,13 @@ class SwitchDSEProblem(DSEProblem):
         cands = list(cands)
         if not cands:
             return []
-        return run_surrogate_batched(
-            [self._arch(c) for c in cands], self._batch_bound(cands),
-            self.trace,
-            back_annotation=self.back_annotation,
-            i_burst=self.features.i_burst,
-            mesh=self.mesh_spec, use_kernel=self.use_kernel).results()
+        with span("spac.stage2", rows=len(cands), events=len(self.trace)):
+            return run_surrogate_batched(
+                [self._arch(c) for c in cands], self._batch_bound(cands),
+                self.trace,
+                back_annotation=self.back_annotation,
+                i_burst=self.features.i_burst,
+                mesh=self.mesh_spec, use_kernel=self.use_kernel).results()
 
     # ------------------------------------------------------------- stage 3
     def size_buffers(self, c, q_occupancy: np.ndarray, eps: float):
@@ -389,14 +391,15 @@ class SwitchDSEProblem(DSEProblem):
         cands = list(cands)
         if not cands:
             return []
-        if self.verify_engine == "cycle":
-            return [self.verify(c) for c in cands]     # rung 4 has no batch form
-        return run_netsim_batched(
-            [self._arch(c) for c in cands], self._batch_bound(cands),
-            self.trace,
-            back_annotation=self.back_annotation,
-            i_burst=self.features.i_burst,
-            mesh=self.mesh_spec, use_kernel=self.use_kernel)
+        with span("spac.stage4", rows=len(cands)):
+            if self.verify_engine == "cycle":
+                return [self.verify(c) for c in cands]  # rung 4 has no batch form
+            return run_netsim_batched(
+                [self._arch(c) for c in cands], self._batch_bound(cands),
+                self.trace,
+                back_annotation=self.back_annotation,
+                i_burst=self.features.i_burst,
+                mesh=self.mesh_spec, use_kernel=self.use_kernel)
 
     def escalate(self, c, v: VerifyResult) -> Optional[VerifyResult]:
         """``verify_engine="auto"``: the front was verified by batched netsim;
