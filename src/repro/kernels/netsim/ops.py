@@ -8,7 +8,18 @@ couplings and conquers each with the structure it actually has:
 * **Port coupling** (departure times) keeps a scan, but a *lean* one — the
   admission-gated port replay (``ref.netsim_replay_abs_ref`` / the Pallas
   candidate-tiled form in ``kernel.py``), with no ``[B, N², D]`` ring.  The
-  ring was ~80% of the old scan's measured wall-clock.
+  ring was ~80% of the old scan's measured wall-clock.  Round 1, where every
+  event is admitted, needs no scan at all: its replay is the crossbar
+  recurrence with earliest start ``now + pipe``, solved by the whole-trace
+  fixed-point sweeps of ``repro.kernels.xbar.xbar_contend_sweep``.  Each
+  event depends only on the last earlier event of its input and output
+  port, so the system is triangular with one fixed point, the serial
+  answer; the sweeps rise monotonically to it, and a sweep that changes
+  nothing has reached it with every element the same ``max`` and ``+`` of
+  the same operands as the scan — bit-identical.  A trace that does not
+  settle within ``SWEEP_CAP`` sweeps runs the scan instead, in the same
+  program.  The gated replays of later rounds keep their scan: their
+  predecessors depend on each row's admission flags.
 * **VOQ coupling** (admission flags) is *per-chain*: whether event k of
   chain (i, j) is dropped depends only on earlier events of the same chain.
   Inside a chain, admitted departures are FIFO (shared input and output
@@ -42,7 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.retrace import track
-from repro.analysis.spans import span
+from repro.analysis.spans import note, span
+from repro.kernels.xbar import xbar_contend_sweep
 
 from .kernel import netsim_replay_padded
 from .ref import netsim_replay_abs_ref
@@ -214,23 +226,17 @@ def _round1_body(now, src, dst, svc_t, pipe, depth, perm, seg_start, rank,
     """Fused first round: ungated replay + all-admitted fullness check.
 
     With all-ones flags the gated recurrence degenerates to the plain port
-    replay, and the admission question needs no compaction at all — the
-    ``rank − depth``-th event of my chain *is* the depth-ago admission, so
-    one ``take_along_axis`` answers fullness for the whole batch.  Returns
-    the replay and a per-row "round 1 is the fixed point" flag; rows where
-    it is (every row, in the sized no-drop regime) are done after this one
-    call."""
-    b_n = svc_t.shape[1]
-
-    def step(carry, xs):
-        in_f, out_f = carry
-        tk, i, j, s = xs
-        start = jnp.maximum(jnp.maximum(tk + pipe, in_f[:, i]), out_f[:, j])
-        end = start + s
-        return (in_f.at[:, i].set(end), out_f.at[:, j].set(end)), end
-
-    zeros = jnp.zeros((b_n, n_ports), svc_t.dtype)
-    _, end_t = jax.lax.scan(step, (zeros, zeros), (now, src, dst, svc_t))
+    replay — the crossbar recurrence with earliest start ``now + pipe``, so
+    it runs as the fixed-point sweeps of ``xbar_contend_sweep`` (bitwise the
+    serial scan; see the module docstring) — and the admission question
+    needs no compaction at all — the ``rank − depth``-th event of my chain
+    *is* the depth-ago admission, so one ``take_along_axis`` answers
+    fullness for the whole batch.  Returns the replay, a per-row "round 1
+    is the fixed point" flag, and the sweep counters ``(sweeps,
+    fell_back)``; rows where the flag holds (every row, in the sized
+    no-drop regime) are done after this one call."""
+    end_t, sweeps, fell_back = xbar_contend_sweep(
+        now[:, None] + pipe[None, :], src, dst, svc_t, n_ports=n_ports)
     end = end_t.T                                           # [B, m]
     e_s = jnp.take(end, perm, axis=1)
     n_s = jnp.take(now, perm)
@@ -239,7 +245,7 @@ def _round1_body(now, src, dst, svc_t, pipe, depth, perm, seg_start, rank,
     oldest = jnp.take_along_axis(e_s, look, axis=1)
     full = (r >= 0) & (oldest > n_s[None, :])
     ok = ~jnp.any(full, axis=1)
-    return end, ok
+    return end, ok, sweeps, fell_back
 
 
 _round1 = track("netsim.kernel.round1",
@@ -252,20 +258,25 @@ _gated_replay = track("netsim.kernel.replay", netsim_replay_abs_ref)
 def _sharded_round1(mesh, n_ports):
     """Round 1 under ``shard_map``: candidate axis split over every mesh
     axis, timeline and chain structure replicated.  Rowwise — no collectives
-    — so each shard is bitwise the single-device call on its slice."""
+    — so each shard is bitwise the single-device call on its slice; each
+    returns its own sweep counters, one per shard."""
     from jax.sharding import PartitionSpec as P
 
     names = tuple(mesh.axis_names)
     cand = P(names)
     rep = P()
-    body = functools.partial(_round1_body, n_ports=n_ports)
+
+    def body(*args):
+        end, ok, sweeps, fell_back = _round1_body(*args, n_ports=n_ports)
+        return end, ok, sweeps[None], fell_back[None]
+
     name = (f"netsim.kernel.round1.sharded["
             f"{'x'.join(map(str, mesh.devices.shape))} "
             f"{','.join(names)} n_ports={n_ports}]")
     return track(name, jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, rep, rep, P(None, names), cand, cand, rep, rep, rep),
-        out_specs=(cand, cand), check_vma=False)))
+        out_specs=(cand,) * 4, check_vma=False)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -393,19 +404,22 @@ def netsim_fixed_point(
         rank_j = jnp.asarray(chain.rank, jnp.int32)
         if k > 1:
             from repro.launch.mesh import shard_pad
-            end, ok = round1(now_j, src_j, dst_j,
-                             jnp.asarray(shard_pad(svc, k).T),
-                             jnp.asarray(shard_pad(pipe, k)),
-                             jnp.asarray(shard_pad(depth32, k)),
-                             perm_j, seg_j, rank_j)
+            out = round1(now_j, src_j, dst_j,
+                         jnp.asarray(shard_pad(svc, k).T),
+                         jnp.asarray(shard_pad(pipe, k)),
+                         jnp.asarray(shard_pad(depth32, k)),
+                         perm_j, seg_j, rank_j)
         else:
-            end, ok = round1(now_j, src_j, dst_j, jnp.asarray(svc.T),
-                             jnp.asarray(pipe), jnp.asarray(depth32),
-                             perm_j, seg_j, rank_j, n_ports=n_ports)
-        # np.array (not asarray): device output views are read-only and the
+            out = round1(now_j, src_j, dst_j, jnp.asarray(svc.T),
+                         jnp.asarray(pipe), jnp.asarray(depth32),
+                         perm_j, seg_j, rank_j, n_ports=n_ports)
+        end, ok, sweeps, fell_back = jax.device_get(out)
+        # the slowest shard's sweeps; whether any shard fell back
+        note(sweeps=int(np.max(sweeps)), scan_fallback=int(np.max(fell_back)))
+        # np.array (not asarray): fetched arrays may be read-only and the
         # subset iteration scatters into end below
         end = np.array(end[:b_n])
-        ok = np.asarray(ok)[:b_n]
+        ok = ok[:b_n]
     admit = np.ones((b_n, m), bool)
     converged = ok.copy()
     if bool(ok.all()):

@@ -1,3 +1,3 @@
-from .ops import xbar_contend
+from .ops import SWEEP_CAP, xbar_contend, xbar_contend_sweep
 
-__all__ = ["xbar_contend"]
+__all__ = ["SWEEP_CAP", "xbar_contend", "xbar_contend_sweep"]

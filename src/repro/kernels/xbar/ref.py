@@ -17,6 +17,16 @@ Two formulations, numerically equivalent, with different dtype trade-offs:
   arrival instant) and returns departure *offsets*, so float32 keeps
   queueing-delay precision no matter how long the trace runs — the
   TPU-native form the Pallas kernel implements.
+
+The float64 program no longer steps through these scans event by event:
+``ops.xbar_contend_sweep`` solves the same recurrence by whole-trace
+fixed-point sweeps.  Each event depends only on the last earlier event of
+its input and of its output port, so the system is triangular with one
+fixed point, the serial answer; the sweeps rise monotonically to it and stop
+on a sweep that changes nothing, where every element is the same ``max``
+and ``+`` of the same operands as here — bit-identical.  The absolute scan
+stays the oracle, and the sweeps fall back to it on a trace that does not
+settle within ``ops.SWEEP_CAP`` sweeps.
 """
 
 from __future__ import annotations
@@ -31,7 +41,7 @@ __all__ = ["xbar_contend_abs_ref", "xbar_contend_slack_ref"]
 
 @functools.partial(jax.jit, static_argnames=("n_ports",))
 def xbar_contend_abs_ref(
-    t: jnp.ndarray,     # [m] float — sorted arrival times, t[0] == 0
+    t: jnp.ndarray,     # [m] or [m, B] float — earliest start per event
     src: jnp.ndarray,   # [m] int32 — source port per packet (shared trace)
     dst: jnp.ndarray,   # [m] int32 — destination port per packet
     svc: jnp.ndarray,   # [B, m] float — per-candidate service time per packet

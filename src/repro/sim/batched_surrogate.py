@@ -7,9 +7,11 @@ the event-driven transaction model as a *sorted-arrival scan over a shared
 trace* in which every per-candidate parameter (bus width, pipeline depth, η,
 ingress stalls, f_clk) is a batch axis:
 
-  * the greedy-crossbar recurrence runs as one ``jax.lax.scan`` with
-    ``[B, n_ports]`` port-availability carries (``repro.kernels.xbar``, with
-    an optional Pallas kernel alongside the iSLIP family),
+  * the greedy-crossbar recurrence runs in one jitted call over the whole
+    batch (``repro.kernels.xbar``): on the float64 path as whole-trace
+    fixed-point sweeps, bit-identical to the serial scan they replace; on
+    float32 as a scan with ``[B, n_ports]`` slack carries (or the optional
+    Pallas kernel),
   * per-candidate departure offsets and sustained throughput come out of the
     same jitted call; latency (one broadcast) and its quantiles reduce on
     the host (numpy's sort beats XLA's CPU sort on the [B, m] matrix by
@@ -37,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.retrace import track
-from repro.analysis.spans import span
+from repro.analysis.spans import note, span
 from repro.core.archspec import SwitchArch, VOQKind
 from repro.core.binding import BoundProtocol
 from repro.core.dse import SurrogateResult
@@ -54,7 +56,8 @@ DEFAULT_QUANTILES = (50.0, 90.0, 99.0)
 
 def _engine_impl(dt, src, dst, svc, t, wire_bits, *, n_ports, use_pallas,
                  interpret):
-    """One call: contention scan + throughput.
+    """One call: contention (fixed-point sweeps on the float64 path, a scan
+    otherwise) + throughput, and the sweep counters ``(sweeps, fell_back)``.
 
     Latency (one broadcast over dep) and quantile reduction deliberately
     stay on the host: returning the [B, m] latency matrix would double the
@@ -63,14 +66,16 @@ def _engine_impl(dt, src, dst, svc, t, wire_bits, *, n_ports, use_pallas,
     candidate carries, replicated timeline), so any partition of the batch —
     including a shard_map split across devices — is bitwise-identical to the
     monolithic call."""
-    dep = xbar_contend(t, dt, src, dst, svc, n_ports=n_ports,
-                       use_pallas=use_pallas, interpret=interpret)
+    dep, sweeps, fell_back = xbar_contend(t, dt, src, dst, svc,
+                                          n_ports=n_ports,
+                                          use_pallas=use_pallas,
+                                          interpret=interpret)
     # dep is absolute on the f64 path, an arrival-relative offset on f32
     absolute = dep.dtype == jnp.float64 and not use_pallas
     dep_end = dep if absolute else t[None, :] + dep
     duration = jnp.maximum(jnp.max(dep_end, axis=1), 1e-12)
     thru = wire_bits / duration / 1e9                           # [B] Gbps
-    return dep, thru
+    return dep, thru, sweeps, fell_back
 
 
 _engine = track("surrogate.engine",
@@ -86,19 +91,24 @@ def _sharded_engine(mesh, n_ports, use_pallas, interpret):
     ``svc`` [B, m] and ``wire_bits`` [B] split along B; the timeline
     (``dt``/``src``/``dst``/``t``) is replicated.  No collectives: rows are
     independent, so each shard runs the serial recurrence on its slice and
-    the result is bitwise-identical to the single-device call."""
+    the result is bitwise-identical to the single-device call.  Each shard
+    returns its own sweep counters, one per shard."""
     from jax.sharding import PartitionSpec as P
 
     cand = P(tuple(mesh.axis_names))
     rep = P()
-    body = functools.partial(_engine_impl, n_ports=n_ports,
-                             use_pallas=use_pallas, interpret=interpret)
+
+    def body(*args):
+        dep, thru, sweeps, fell_back = _engine_impl(
+            *args, n_ports=n_ports, use_pallas=use_pallas, interpret=interpret)
+        return dep, thru, sweeps[None], fell_back[None]
+
     name = (f"surrogate.sharded[{'x'.join(map(str, mesh.devices.shape))} "
             f"{','.join(mesh.axis_names)} n_ports={n_ports}]")
     return track(name, jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, rep, rep, cand, rep, cand),
-        out_specs=(cand, cand), check_vma=False)))
+        out_specs=(cand,) * 4, check_vma=False)))
 
 
 def _exact_occupancy(t, qid, dep):
@@ -261,14 +271,17 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
             engine = functools.partial(_engine, n_ports=n,
                                        use_pallas=use_pallas,
                                        interpret=interpret)
-        # the device call: copies in, the scan, and the fetch back
+        # the device call: copies in, the sweeps, and the fetch back
         with span("spac.stage2.scan", jit=jitted):
             if precision == "float64":
                 with jax.enable_x64():
-                    dep, thru = engine(*args)
-                    dep, thru = np.asarray(dep), np.asarray(thru)
+                    dep, thru, sweeps, fell_back = jax.device_get(
+                        engine(*args))
+                # the slowest shard's sweeps; whether any shard fell back
+                note(sweeps=int(np.max(sweeps)),
+                     scan_fallback=int(np.max(fell_back)))
             else:
-                dep, thru = engine(*args)
+                dep, thru, _, _ = engine(*args)
                 dep, thru = (np.asarray(dep, np.float64),
                              np.asarray(thru, np.float64))
         dep, thru = dep[:b_n], thru[:b_n]       # strip pad rows (no-op serial)

@@ -172,9 +172,10 @@ def test_pallas_xbar_matches_slack_oracle(m, b, n, seed, horizon):
     svc = np.abs(rng.normal(1e-8, 2e-9, (b, m))).astype(np.float32)
     ref = xbar_contend_slack_ref(jnp.asarray(dt), jnp.asarray(src),
                                  jnp.asarray(dst), jnp.asarray(svc), n_ports=n)
-    pal = xbar_contend(jnp.asarray(t, jnp.float32), jnp.asarray(dt),
-                       jnp.asarray(src), jnp.asarray(dst), jnp.asarray(svc),
-                       n_ports=n, use_pallas=True, interpret=True)
+    pal, _, _ = xbar_contend(jnp.asarray(t, jnp.float32), jnp.asarray(dt),
+                             jnp.asarray(src), jnp.asarray(dst),
+                             jnp.asarray(svc), n_ports=n, use_pallas=True,
+                             interpret=True)
     np.testing.assert_array_equal(np.asarray(pal), np.asarray(ref))
 
 

@@ -387,6 +387,103 @@ def test_problem_rejects_unknown_use_kernel():
 
 
 # --------------------------------------------------------------------------
+# fixed-point sweeps (stage 2, round 1) vs the per-event port scans
+# --------------------------------------------------------------------------
+
+def _port_with_one_event():
+    """hft with event 5 moved onto a ninth port, in and out: both its
+    predecessors are the zero row, and it is no one's predecessor."""
+    tr = hft(seed=0)
+    src, dst = tr.src.copy(), tr.dst.copy()
+    src[5] = dst[5] = 8
+    return Trace("alone", tr.time_s, src, dst, tr.payload_bytes, 9,
+                 tr.link_gbps)
+
+
+#: trace, candidate rows, service rate as a multiple of the line rate
+SWEEP_CASES = {
+    "datacenter": (lambda: datacenter(seed=0), 40, (1.0, 4.0)),
+    "hft": (lambda: hft(seed=0), 24, (2.0, 4.0)),
+    # 0.95 of line rate in bursts: a busy chain longer than SWEEP_CAP
+    "saturated": (lambda: hft(seed=0, load=0.95), 6, (1.0, 1.0)),
+    "one_row": (lambda: datacenter(seed=1), 1, (1.0, 1.0)),
+    "port_with_one_event": (_port_with_one_event, 5, (2.0, 4.0)),
+}
+
+
+def _service(wire_bytes, link_gbps, rows, speed):
+    rate = link_gbps * 1e9 * np.random.default_rng(0).uniform(*speed, rows)
+    return wire_bytes[None, :] * 8.0 / rate[:, None]        # [rows, m]
+
+
+def _round1_scan(now, src, dst, svc_t, pipe, n_ports):
+    """The per-event round-1 port scan the sweeps replaced."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(carry, xs):
+        in_f, out_f = carry
+        tk, i, j, s = xs
+        start = jnp.maximum(jnp.maximum(tk + pipe, in_f[:, i]), out_f[:, j])
+        end = start + s
+        return (in_f.at[:, i].set(end), out_f.at[:, j].set(end)), end
+
+    zeros = jnp.zeros((svc_t.shape[1], n_ports), svc_t.dtype)
+    return jax.lax.scan(step, (zeros, zeros), (now, src, dst, svc_t))[1].T
+
+
+@pytest.mark.parametrize("stage", ["stage2", "round1"])
+@pytest.mark.parametrize("case", sorted(SWEEP_CASES))
+def test_sweeps_match_port_scan_bitwise(case, stage):
+    """The sweep form inside ``surrogate.engine`` and
+    ``netsim.kernel.round1`` is bit for bit the serial scan, whether it
+    settles within ``SWEEP_CAP`` sweeps or falls back to the scan."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.netsim.ops import _round1
+    from repro.kernels.xbar import SWEEP_CAP
+    from repro.kernels.xbar.ref import xbar_contend_abs_ref
+    from repro.sim.batched_surrogate import _engine
+
+    make, rows, speed = SWEEP_CASES[case]
+    tr = make()
+    n = tr.n_ports
+    with jax.enable_x64():
+        if stage == "stage2":
+            tl = tlmod.stage2_timeline(tr, n)
+            t, src, dst = (jnp.asarray(tl.t), jnp.asarray(tl.src, jnp.int32),
+                           jnp.asarray(tl.dst, jnp.int32))
+            svc = jnp.asarray(_service(tl.payload + 42, tr.link_gbps, rows,
+                                       speed))
+            got, _, sweeps, fell_back = _engine(
+                jnp.asarray(tl.dt), src, dst, svc, t, jnp.ones(rows),
+                n_ports=n, use_pallas=False, interpret=False)
+            want = xbar_contend_abs_ref(t, src, dst, svc, n_ports=n)
+        else:
+            tl = tlmod.stage4_timeline(tr, n, 42, 0.0)
+            now, src, dst = (jnp.asarray(tl.now),
+                             jnp.asarray(tl.src_o, jnp.int32),
+                             jnp.asarray(tl.dst_o, jnp.int32))
+            svc_t = jnp.asarray(_service(tl.wire_e, tr.link_gbps, rows,
+                                         speed).T)
+            pipe = jnp.asarray(np.linspace(0.0, 5e-8, rows))
+            c = tl.chain
+            got, _, sweeps, fell_back = _round1(
+                now, src, dst, svc_t, pipe, jnp.full(rows, 64, jnp.int32),
+                jnp.asarray(c.perm, jnp.int32), jnp.asarray(c.seg_start),
+                jnp.asarray(c.rank), n_ports=n)
+            want = _round1_scan(now, src, dst, svc_t, pipe, n)
+        got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape == (rows, len(tr))
+    np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))
+    if case == "saturated":
+        assert (int(sweeps), int(fell_back)) == (SWEEP_CAP, 1)
+    else:
+        assert 1 <= int(sweeps) < SWEEP_CAP and int(fell_back) == 0
+
+
+# --------------------------------------------------------------------------
 # mesh x kernel composition (forced host devices, subprocess)
 # --------------------------------------------------------------------------
 

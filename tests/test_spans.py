@@ -115,6 +115,15 @@ def test_stage_counters(explored):
     assert s4.attrs["fallback_rows"] == 0
 
 
+@pytest.mark.parametrize("name", ["spac.stage2.scan", "spac.stage4.round1"])
+def test_device_calls_note_their_sweeps(explored, name):
+    from repro.kernels.xbar import SWEEP_CAP
+
+    rec = _one(explored[1], name)
+    assert 1 <= rec.attrs["sweeps"] < SWEEP_CAP
+    assert rec.attrs["scan_fallback"] == 0
+
+
 def test_profiler_host_plane_holds_the_spans(explored):
     _, recs, logdir = explored
     path = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
