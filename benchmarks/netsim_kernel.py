@@ -13,8 +13,7 @@ latency arrays — no tolerance): a speedup measured against diverged results
 never lands in ``BENCH_dse.json``.  The report also carries the honest
 batch composition — how many of the 256 rows are unique dynamics after
 dedup (replicated archs collapse; real NSGA-II generations have the same
-property, which is exactly why the dedup exists) — plus the stage-2
-segmented-occupancy speedup as a secondary line.
+property, which is exactly why the dedup exists).
 
     python -m benchmarks.netsim_kernel
 """
@@ -76,11 +75,6 @@ def run():
         for vb, vr in zip(ref4, got4))
     speedup = t_def / t_ker
 
-    _, t2_def = _best_of(lambda: run_surrogate_batched(
-        cands, bound, tr, back_annotation=False, use_kernel=False))
-    _, t2_ker = _best_of(lambda: run_surrogate_batched(
-        cands, bound, tr, back_annotation=False, use_kernel=True))
-
     m = len(tr)
     emit("netsim_kernel/stage4_default", t_def * 1e6,
          f"{BATCH / t_def:.0f} cand/s over B={BATCH} m={m}")
@@ -91,8 +85,6 @@ def run():
          f"{speedup:.1f}x ({verdict} >={SPEEDUP_BAR:.0f}x bar)")
     emit("netsim_kernel/stage4_parity", 0.0,
          "PASS bitwise" if parity else "FAIL")
-    emit("netsim_kernel/stage2_speedup", 0.0,
-         f"{t2_def / t2_ker:.2f}x segmented occupancy")
 
     out = {
         "batch": BATCH, "events": m, "unique_rows": uniq,
@@ -101,8 +93,6 @@ def run():
         "stage4_kernel_cands_per_sec": BATCH / t_ker,
         "stage4_speedup": speedup, "speedup_bar": SPEEDUP_BAR,
         "stage4_parity_bitwise": parity,
-        "stage2_default_time_s": t2_def, "stage2_kernel_time_s": t2_ker,
-        "stage2_speedup": t2_def / t2_ker,
         "pass": parity and speedup >= SPEEDUP_BAR,
     }
     if not parity:

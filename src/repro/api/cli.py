@@ -265,8 +265,8 @@ def _add_override_flags(p: argparse.ArgumentParser) -> None:
                         "cycle-accurate datapath for every survivor, or "
                         "auto (netsim front + cycle-sim champion)")
     g.add_argument("--use-kernel", choices=USE_KERNEL_MODES, default=None,
-                   help="segmented netsim kernels for the batched stage-2/4 "
-                        "engines: auto (kernel when available, bit-exact "
+                   help="segmented netsim kernels for the batched stage-4 "
+                        "engine: auto (kernel when available, bit-exact "
                         "oracle fallback), on, or off (legacy scans)")
     from repro.core.search import SEARCH_ALGORITHMS
     gs = p.add_argument_group(

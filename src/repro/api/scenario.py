@@ -387,7 +387,7 @@ class Fidelity:
     #: cycle-accurate datapath for every survivor (slow); "auto" verifies the
     #: front with batched netsim and escalates only the champion to cycle-sim
     verify_engine: str = "netsim"
-    #: segmented netsim-kernel knob for the batched stage-2/4 engines:
+    #: segmented netsim-kernel knob for the batched stage-4 engine:
     #: "auto" (kernel when available, oracle fallback), "on", "off".
     #: Bools normalise to "on"/"off" so JSON round-trips stay canonical.
     use_kernel: str = "auto"

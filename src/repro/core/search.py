@@ -757,7 +757,11 @@ def run_search(problem, spec: SearchSpec, sla: SLA, *, delta: float = 0.2,
             cands = driver.ask_candidates()
             srs = problem.surrogate_batch(cands)
             driver.tell_candidates(srs)
-        return driver.finalize()
+        outcome = driver.finalize()
+        # this call's generations and the candidate rows it priced
+        note(generations=driver.engine.generation - start_gen,
+             evaluated_rows=driver.surrogate_rows)
+        return outcome
 
 
 # --------------------------------------------------------------------------

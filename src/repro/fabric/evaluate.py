@@ -296,7 +296,6 @@ def surrogate_fabric_batched(
     back_annotation: bool = False,
     i_burst: float = 1.0,
     mesh=None,
-    use_kernel=False,
 ) -> List[SurrogateResult]:
     """Stage-2 screen for fabric candidates: one batched surrogate call per
     tier over that tier's *merged traversal trace* (every hop through the
@@ -343,7 +342,7 @@ def surrogate_fabric_batched(
             [cands_bounds[b][t] for b in range(n_cands)],
             sub, hw=[hw[b][t] for b in range(n_cands)],
             back_annotation=back_annotation, i_burst=i_burst,
-            mesh=mesh, use_kernel=use_kernel).results()
+            mesh=mesh).results()
         occ = np.empty((n_cands, pkt_idx.size))
         for b, sr in enumerate(res):
             lat_trav = np.empty(pkt_idx.size, np.float64)

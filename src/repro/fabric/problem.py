@@ -242,7 +242,7 @@ class FabricDSEProblem(DSEProblem):
             self.trace,
             back_annotation=self.back_annotation,
             i_burst=self.features.i_burst,
-            mesh=self.mesh_spec, use_kernel=self.use_kernel)
+            mesh=self.mesh_spec)
 
     # ------------------------------------------------------------- stage 3
     def size_buffers(self, cand: FabricCandidate, q_occupancy: np.ndarray,

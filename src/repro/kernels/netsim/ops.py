@@ -108,16 +108,18 @@ class ChainIndex:
     """Per-(src,dst) chain structure of one time-ordered event timeline.
 
     ``perm`` stably sorts events by chain id (time order preserved inside a
-    chain), ``inv`` undoes it, ``seg_start[p]``/``rank[p]`` give, for the
-    event at *permuted* position p, its chain's first permuted position and
-    its arrival rank within the chain.  Pure function of (timeline, n_ports)
-    — computed once per trace by ``sim.timeline`` and reused across every
-    generation, candidate and campaign scenario."""
+    chain), ``inv`` undoes it, ``seg_start[p]``/``rank[p]``/``chain_id[p]``
+    give, for the event at *permuted* position p, its chain's first permuted
+    position, its arrival rank within the chain and the chain's compact id.
+    Pure function of (timeline, n_ports) — computed once per trace by
+    ``sim.timeline`` and reused across every generation, candidate and
+    campaign scenario."""
 
     perm: np.ndarray        # [m] intp — stable argsort of chain ids
     inv: np.ndarray         # [m] intp — inverse permutation
     seg_start: np.ndarray   # [m] int32 — chain block start, permuted domain
     rank: np.ndarray        # [m] int32 — arrivals-before-me within my chain
+    chain_id: np.ndarray    # [m] int32 — compact chain id 0..n_chains-1
     n_chains: int
 
 
@@ -134,7 +136,8 @@ def build_chain_index(qid: np.ndarray) -> ChainIndex:
     inv = np.empty(m, np.intp)
     inv[perm] = np.arange(m)
     return ChainIndex(perm=perm.astype(np.intp), inv=inv, seg_start=seg_start,
-                      rank=rank, n_chains=int(starts.size))
+                      rank=rank, chain_id=run_ids.astype(np.int32),
+                      n_chains=int(starts.size))
 
 
 # --------------------------------------------------------------------------
@@ -176,45 +179,41 @@ def segmented_admission(end: np.ndarray, admit: np.ndarray, now: np.ndarray,
 
 
 # --------------------------------------------------------------------------
-# segmented occupancy: stage 2's per-VOQ counts without the per-row loop
+# segmented occupancy: stage 2's exact per-VOQ counts on integer keys
 # --------------------------------------------------------------------------
 
 def segmented_occupancy(t: np.ndarray, dep: np.ndarray,
                         chain: ChainIndex) -> np.ndarray:
-    """Exact per-VOQ occupancy at arrival instants, one searchsorted total.
+    """Per-VOQ occupancy at arrival instants, exact by construction.
 
-    The serial reference (``batched_surrogate._exact_occupancy``) runs one
-    ``searchsorted`` per candidate row.  Occupancy at event k is
-    ``(chain arrivals ≤ k) − (chain departures ≤ now_k) − 1`` — a prefix
-    count over chain segments.  Departures are FIFO inside a chain (shared
-    ports), so the chain-major key ``chain·span + time`` is globally sorted
-    per row; adding a per-row offset makes the whole [B, m] block one sorted
-    key stream and a *single* flat ``np.searchsorted`` answers every
-    (candidate, event) query at once.
+    Occupancy at event k is ``(chain arrivals before k) − (chain departures
+    at or before t_k)``, the serial engine's per-queue ``searchsorted``.
+    Every comparison here is exact: no time is ever added to an id.
 
-    Precision: the composite key spends ~log2(B·n²) mantissa bits on the
-    (row, chain) id — ≈14 bits at B=256, n=8, leaving time resolution of
-    span·2⁻³⁸ ≈ femtoseconds on the registry traces, far below any service
-    time.  Counts are integers and bit-identical to the serial reference on
-    every registry workload (asserted in ``tests/test_netsim_kernels.py``).
+    1. Each departure time becomes an event index: ``vis = searchsorted(t,
+       dep, "left")`` is the first arrival at or after it, so a departure
+       has happened by arrival k exactly when ``vis ≤ k`` (``t`` is sorted;
+       one float64 comparison per step, nothing rounded).
+    2. Departures are FIFO inside a chain (shared input and output port), so
+       ``vis`` rises along each chain, and the integer keys ``chain_id·(m+1)
+       + vis`` are sorted over a whole row.  One integer ``searchsorted`` of
+       the arrivals' keys ``chain_id·(m+1) + k`` counts, for every event,
+       the departures of earlier chains (``seg_start``) plus its own chain's
+       departures by its arrival.
+
+    Keys stay below ``n_chains·(m+1)``, far inside int64.  One pass per
+    candidate row; the chain structure comes from the trace memo.
     """
     b_n, m = dep.shape
     perm = chain.perm
-    pos = np.arange(m, dtype=np.int64)
-    span = max(float(dep.max(initial=0.0)), float(t.max(initial=0.0))) + 1.0
-    # chain id per permuted slot: seg_start is constant within a chain and
-    # unique across chains, so it serves as a compact chain id
-    g = chain.seg_start.astype(np.float64)
-    key_dep = g[None, :] * span + dep[:, perm]
-    key_arr = g * span + t[perm]
-    big = (float(chain.seg_start[-1]) + 2.0) * span if m else 1.0
-    rows = np.arange(b_n, dtype=np.float64)[:, None] * big
-    fd = (key_dep + rows).ravel()
-    fa = (key_arr[None, :] + rows).ravel()
-    departed = (np.searchsorted(fd, fa, side="right").reshape(b_n, m)
-                - np.arange(b_n, dtype=np.int64)[:, None] * m)
-    occ_s = (pos[None, :] + 1) - departed - 1
-    return occ_s[:, chain.inv]
+    base = chain.chain_id.astype(np.int64) * (m + 1)
+    key_arr = perm + base
+    departed = np.empty((b_n, m), np.int64)
+    for b in range(b_n):
+        key_dep = np.searchsorted(t, dep[b], side="left")[perm] + base
+        departed[b] = np.searchsorted(key_dep, key_arr, side="right")
+    departed -= chain.seg_start
+    return (chain.rank - departed)[:, chain.inv]
 
 
 # --------------------------------------------------------------------------
@@ -344,24 +343,6 @@ def lean_replay(now, src, dst, svc, pipe, admit, *, n_ports: int,
     return dep[:m, :b_n].T
 
 
-def _pad_rows(a: np.ndarray, size: int) -> np.ndarray:
-    """Pad the candidate axis to ``size`` by replicating row 0 (a no-op
-    workload: rowwise engines ignore replicas, callers strip them)."""
-    if a.shape[0] == size:
-        return a
-    reps = np.repeat(a[:1], size - a.shape[0], axis=0)
-    return np.concatenate([a, reps], axis=0)
-
-
-def _bucket(b_n: int, k: int) -> int:
-    """Compile-friendly batch size: next power of two, then up to a multiple
-    of the shard count — subset iterations reuse O(log B) compiled shapes."""
-    size = 1 << max(b_n - 1, 0).bit_length()
-    if k > 1:
-        size = -(-size // k) * k
-    return size
-
-
 def netsim_fixed_point(
     now: np.ndarray,       # [m] sorted switch-arrival times
     src: np.ndarray,       # [m] int32
@@ -381,11 +362,14 @@ def netsim_fixed_point(
     batch (one jitted call, mesh-sharded when ``mesh_spec`` names devices).
     Rows whose all-admitted replay is already self-consistent — every row,
     when stage-3 sizing did its job — are final.  The rest iterate
-    replay ↔ ``segmented_admission`` on the row subset only (padded to a
-    power-of-two bucket so compiles stay O(log B)); unconverged rows after
-    ``max_rounds`` are flagged for the caller's serial fallback.  Callers
-    must handle ``depth < 1`` rows themselves (serial semantics drop every
-    packet; no replay needed)."""
+    replay ↔ ``segmented_admission`` on the row subset only; unconverged
+    rows after ``max_rounds`` are flagged for the caller's serial fallback.
+    Callers must handle ``depth < 1`` rows themselves (serial semantics drop
+    every packet; no replay needed).  Every device call pads its candidate
+    axis to ``repro.launch.mesh.bucket_size`` rows (replicas of row 0,
+    stripped on the fetch), so widths share compiled programs."""
+    from repro.launch.mesh import bucket_pad
+
     b_n, m = svc.shape
     if np.any(depth < 1):
         raise ValueError("netsim_fixed_point requires depth >= 1 rows")
@@ -395,24 +379,19 @@ def netsim_fixed_point(
     round1 = _sharded_round1(mesh_spec.build(), n_ports) if k > 1 else _round1
     # the device call: copies in (the timeline stays for later rounds), the
     # fused round, and the fetch back
-    with span("spac.stage4.round1", jit=round1):
+    svc_p = bucket_pad(svc, k)
+    with span("spac.stage4.round1", jit=round1, rows=b_n,
+              pad_rows=svc_p.shape[0] - b_n):
         now_j = jnp.asarray(now)
         src_j = jnp.asarray(src, jnp.int32)
         dst_j = jnp.asarray(dst, jnp.int32)
         perm_j = jnp.asarray(chain.perm, jnp.int32)
         seg_j = jnp.asarray(chain.seg_start, jnp.int32)
         rank_j = jnp.asarray(chain.rank, jnp.int32)
-        if k > 1:
-            from repro.launch.mesh import shard_pad
-            out = round1(now_j, src_j, dst_j,
-                         jnp.asarray(shard_pad(svc, k).T),
-                         jnp.asarray(shard_pad(pipe, k)),
-                         jnp.asarray(shard_pad(depth32, k)),
-                         perm_j, seg_j, rank_j)
-        else:
-            out = round1(now_j, src_j, dst_j, jnp.asarray(svc.T),
-                         jnp.asarray(pipe), jnp.asarray(depth32),
-                         perm_j, seg_j, rank_j, n_ports=n_ports)
+        args = (now_j, src_j, dst_j, jnp.asarray(svc_p.T),
+                jnp.asarray(bucket_pad(pipe, k)),
+                jnp.asarray(bucket_pad(depth32, k)), perm_j, seg_j, rank_j)
+        out = round1(*args) if k > 1 else round1(*args, n_ports=n_ports)
         end, ok, sweeps, fell_back = jax.device_get(out)
         # the slowest shard's sweeps; whether any shard fell back
         note(sweeps=int(np.max(sweeps)), scan_fallback=int(np.max(fell_back)))
@@ -438,20 +417,14 @@ def netsim_fixed_point(
     conv_sub = np.zeros(rows.size, bool)
     while rounds < max_rounds:
         rounds += 1
-        size = _bucket(rows.size, k)
-        svc_p = _pad_rows(sub_svc, size)
-        admit_p = _pad_rows(cur, size)
-        pipe_p = _pad_rows(sub_pipe, size)
-        with span("spac.stage4.replay", jit=replay, round=rounds):
-            if k > 1:
-                sub_end = np.asarray(replay(
-                    now_j, src_j, dst_j, jnp.asarray(svc_p),
-                    jnp.asarray(pipe_p), jnp.asarray(admit_p)))[:rows.size]
-            else:
-                sub_end = np.asarray(replay(
-                    now_j, src_j, dst_j, jnp.asarray(svc_p),
-                    jnp.asarray(pipe_p), jnp.asarray(admit_p),
-                    n_ports=n_ports))[:rows.size]
+        svc_p = bucket_pad(sub_svc, k)
+        args = (now_j, src_j, dst_j, jnp.asarray(svc_p),
+                jnp.asarray(bucket_pad(sub_pipe, k)),
+                jnp.asarray(bucket_pad(cur, k)))
+        with span("spac.stage4.replay", jit=replay, round=rounds,
+                  rows=rows.size, pad_rows=svc_p.shape[0] - rows.size):
+            out = replay(*args) if k > 1 else replay(*args, n_ports=n_ports)
+            sub_end = np.asarray(out)[:rows.size]
         with span("spac.stage4.admission", round=rounds):
             derived = segmented_admission(sub_end, cur, now, sub_depth, chain)
         eq = (derived == cur).all(axis=1)

@@ -17,7 +17,8 @@ import jax
 import numpy as np
 
 __all__ = ["make_mesh", "make_production_mesh", "plan_for_mesh",
-           "N_DEVICES", "MeshSpec", "padded_size", "shard_pad", "shard_unpad"]
+           "N_DEVICES", "MeshSpec", "padded_size", "shard_pad", "shard_unpad",
+           "bucket_size", "bucket_pad"]
 
 N_DEVICES = {"single": 256, "multi": 512}
 
@@ -146,6 +147,29 @@ def shard_pad(a: np.ndarray, k: int, axis: int = 0) -> np.ndarray:
         return a
     fill = np.repeat(np.take(a, [0], axis=axis), pad, axis=axis)
     return np.concatenate([np.asarray(a), fill], axis=axis)
+
+
+def bucket_size(n: int, k: int = 1) -> int:
+    """The candidate-axis width a device call of ``n`` rows pads to: the
+    next multiple of 8 up to 16, then the next of 24, 32, 48, 64, 96, ...
+    (powers of two and 1.5x powers of two), then up to a multiple of the
+    shard count ``k``.  One rule for every stage-2 and stage-4 call, so a
+    search whose generations ask for any width from 1 to 48 compiles five
+    programs (8, 16, 24, 32, 48); above 16 rows a bucket is under a third
+    pad."""
+    if n <= 16:
+        size = 8 * max(1, -(-n // 8))
+    else:
+        size = 1 << (n - 1).bit_length()
+        if 4 * n <= 3 * size:
+            size = size * 3 // 4
+    return padded_size(size, k)
+
+
+def bucket_pad(a: np.ndarray, k: int = 1, axis: int = 0) -> np.ndarray:
+    """Pad ``a`` along ``axis`` to ``bucket_size`` by replicating row 0,
+    as :func:`shard_pad` does; the caller strips the pad rows."""
+    return shard_pad(a, bucket_size(a.shape[axis], k), axis)
 
 
 def shard_unpad(a, n: int, axis: int = 0):

@@ -224,30 +224,22 @@ def _run_group(archs, bounds, trace, hw_list, cfg,
     # the compiled ring size — and the scan it keys — is mesh-invariant
     d_max = 1 << int(int(mod.max()) - 1).bit_length()
 
+    # the candidate axis pads to its bucket width (replicas of row 0,
+    # stripped on the fetch), a multiple of the shard count under a mesh
+    from repro.launch.mesh import bucket_pad
     k = 1 if mesh_spec is None else mesh_spec.shard_axis
     engine = (_sharded_verify_engine(mesh_spec.build(), n, d_max) if k > 1
               else _verify_engine)
-    with span("spac.stage4.scan", jit=engine):
-        if k > 1:
-            from repro.launch.mesh import shard_pad
-            svc_p = shard_pad(svc, k)
-            with jax.enable_x64():
-                end, admit = engine(
-                    jnp.asarray(now), jnp.asarray(tl4.src_o, jnp.int32),
-                    jnp.asarray(tl4.dst_o, jnp.int32),
-                    jnp.asarray(svc_p[:, order].T),
-                    jnp.asarray(shard_pad(pipe, k)),
-                    jnp.asarray(shard_pad(depth, k), jnp.int32),
-                    jnp.asarray(shard_pad(mod, k)))
-        else:
-            with jax.enable_x64():
-                end, admit = engine(
-                    jnp.asarray(now), jnp.asarray(tl4.src_o, jnp.int32),
-                    jnp.asarray(tl4.dst_o, jnp.int32),
-                    jnp.asarray(svc[:, order].T),
-                    jnp.asarray(pipe), jnp.asarray(depth, jnp.int32),
-                    jnp.asarray(mod), n_ports=n, d_max=d_max)
-        end = np.asarray(end, np.float64)[:b_n]  # strip pad rows (no-op serial)
+    svc_p = bucket_pad(svc, k)
+    args = (now, tl4.src_o.astype(np.int32), tl4.dst_o.astype(np.int32),
+            svc_p[:, order].T, bucket_pad(pipe, k),
+            bucket_pad(depth, k).astype(np.int32), bucket_pad(mod, k))
+    with span("spac.stage4.scan", jit=engine, rows=b_n,
+              pad_rows=svc_p.shape[0] - b_n):
+        with jax.enable_x64():
+            end, admit = (engine(*args) if k > 1
+                          else engine(*args, n_ports=n, d_max=d_max))
+        end = np.asarray(end, np.float64)[:b_n]  # strip pad rows
         admit = np.asarray(admit, bool)[:b_n]
 
     with span("spac.stage4.reduce"):

@@ -17,9 +17,11 @@ ingress stalls, f_clk) is a batch axis:
     the host (numpy's sort beats XLA's CPU sort on the [B, m] matrix by
     ~10x, measured, and shipping a second [B, m] matrix off-device would
     double the transfer),
-  * exact per-VOQ occupancy counting (PASTA sampling) is integer math done
-    once on the host from the batched departure times — bit-identical to the
-    serial path, so stage-3 sizing and drop counts cannot drift.
+  * exact per-VOQ occupancy counting (PASTA sampling) is done once on the
+    host from the batched departure times by
+    ``repro.kernels.netsim.segmented_occupancy``: exact comparisons and
+    integer keys, equal to the serial path's counts by construction, so
+    stage-3 sizing and drop counts cannot drift.
 
 Precision: with ``precision="float64"`` (default) the scan runs under a
 scoped ``jax.enable_x64`` so departure times match the serial
@@ -46,7 +48,7 @@ from repro.core.dse import SurrogateResult
 
 from .backannotate import HardwareParams, annotate
 from .timeline import stage2_timeline
-from repro.kernels.netsim import resolve_use_kernel, segmented_occupancy
+from repro.kernels.netsim import segmented_occupancy
 from repro.kernels.xbar import xbar_contend
 
 __all__ = ["BatchedSurrogateResult", "run_surrogate_batched", "DEFAULT_QUANTILES"]
@@ -109,42 +111,6 @@ def _sharded_engine(mesh, n_ports, use_pallas, interpret):
         body, mesh=mesh,
         in_specs=(rep, rep, rep, cand, rep, cand),
         out_specs=(cand,) * 4, check_vma=False)))
-
-
-def _exact_occupancy(t, qid, dep):
-    """Per-VOQ occupancy at arrival instants for every candidate at once.
-
-    Serial reference loops ``np.searchsorted`` per queue; here one
-    searchsorted per candidate row covers all queues: keys ``qid*span + time``
-    order departures queue-major (FIFO keeps them sorted inside a queue).
-    All float64 — the counts are exact integers identical to the serial
-    model's.  The key spends ~log2(n_ports²) mantissa bits on the queue id,
-    leaving time resolution of span·n²·2⁻⁵² (≈ femtoseconds even at 1024
-    ports — far below any physical service-time margin); the candidate axis
-    deliberately stays a Python loop rather than a third key term so batch
-    size cannot erode that budget.
-    """
-    b_n, m = dep.shape
-    order = np.argsort(qid, kind="stable")
-    g = qid[order]
-    first = np.ones(m, bool)
-    first[1:] = g[1:] != g[:-1]
-    run_starts = np.nonzero(first)[0]
-    run_ids = np.cumsum(first) - 1
-    rank_grouped = np.arange(m) - run_starts[run_ids]
-    rank = np.empty(m, np.int64)
-    rank[order] = rank_grouped                 # arrivals-before-me in my queue
-    qstart = np.empty(m, np.int64)
-    qstart[order] = run_starts[run_ids]        # my queue's block start position
-
-    span = max(float(dep.max(initial=0.0)), float(t.max(initial=0.0))) + 1.0
-    key_arr = qid * span + t
-    occ = np.empty((b_n, m), np.int64)
-    for b in range(b_n):
-        key_dep = g * span + dep[b, order]
-        departed = np.searchsorted(key_dep, key_arr, side="right") - qstart
-        occ[b] = rank - departed
-    return occ                                 # [B, m] int64
 
 
 @dataclasses.dataclass
@@ -210,7 +176,7 @@ class BatchedSurrogateResult:
 
 
 def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
-               quantiles, mesh_spec=None, use_kernel=False):
+               quantiles, mesh_spec=None):
     """All candidates share n_ports; every other parameter — including the
     protocol's header wire-bytes under co-design — is a batch axis.  The
     shared arrival timeline is the trace's (candidate-independent), so mixed
@@ -251,28 +217,26 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
         thru = np.zeros(b_n)
     else:
         dt = tl2.dt
+        # pad the candidate axis to its bucket width (throwaway replicas of
+        # row 0, stripped below) so widths share compiled programs; with a
+        # mesh it is also a multiple of the shard count, split over every
+        # mesh axis
+        from repro.launch.mesh import bucket_pad
         k = 1 if mesh_spec is None else mesh_spec.shard_axis
+        args = (dt.astype(dtype), src.astype(np.int32), dst.astype(np.int32),
+                bucket_pad(svc.astype(dtype), k), t.astype(dtype),
+                bucket_pad(wire_bits.astype(dtype), k))
         if k > 1:
-            # pad the candidate axis to the mesh extent (throwaway replicas
-            # of row 0, stripped below) and shard it over every mesh axis
-            from repro.launch.mesh import shard_pad
-            args = (dt.astype(dtype), src.astype(np.int32),
-                    dst.astype(np.int32),
-                    shard_pad(svc.astype(dtype), k),
-                    t.astype(dtype),
-                    shard_pad(wire_bits.astype(dtype), k))
             engine = jitted = _sharded_engine(mesh_spec.build(), n, use_pallas,
                                               interpret)
         else:
-            args = (dt.astype(dtype), src.astype(np.int32),
-                    dst.astype(np.int32), svc.astype(dtype), t.astype(dtype),
-                    wire_bits.astype(dtype))
             jitted = _engine
             engine = functools.partial(_engine, n_ports=n,
                                        use_pallas=use_pallas,
                                        interpret=interpret)
         # the device call: copies in, the sweeps, and the fetch back
-        with span("spac.stage2.scan", jit=jitted):
+        with span("spac.stage2.scan", jit=jitted, rows=b_n,
+                  pad_rows=args[3].shape[0] - b_n):
             if precision == "float64":
                 with jax.enable_x64():
                     dep, thru, sweeps, fell_back = jax.device_get(
@@ -284,7 +248,7 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
                 dep, thru, _, _ = engine(*args)
                 dep, thru = (np.asarray(dep, np.float64),
                              np.asarray(thru, np.float64))
-        dep, thru = dep[:b_n], thru[:b_n]       # strip pad rows (no-op serial)
+        dep, thru = dep[:b_n], thru[:b_n]       # strip pad rows
     with span("spac.stage2.reduce"):
         if precision == "float64":
             # the f64 scan returns absolute departure times so the occupancy
@@ -297,16 +261,8 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
             lat = (dep + pipe_s[:, None]) * 1e9
         quant = (np.percentile(lat, quantiles, axis=1).T if m
                  else np.zeros((b_n, len(quantiles))))
-        if m == 0:
-            occupancy = np.zeros((b_n, 0), np.int64)
-        elif use_kernel:
-            # one flat searchsorted over the whole [B, m] block (chain
-            # structure from the trace memo) — integer counts bit-identical
-            # to the serial per-row reference, asserted in
-            # tests/test_netsim_kernels.py
-            occupancy = segmented_occupancy(np.asarray(t), dep_end, tl2.chain)
-        else:
-            occupancy = _exact_occupancy(t, tl2.qid, dep_end)
+        with span("spac.stage2.occupancy", rows=b_n, events=m):
+            occupancy = segmented_occupancy(t, dep_end, tl2.chain)
     return BatchedSurrogateResult(
         archs=list(archs), hw=list(hw_list),
         latency_ns=np.asarray(lat, np.float64),
@@ -314,8 +270,7 @@ def _run_group(archs, bounds, trace, hw_list, use_pallas, interpret, precision,
         throughput_gbps=np.asarray(thru, np.float64),
         q_occupancy=occupancy, dep_end_s=dep_end, t_s=t,
         line_rate_feasible=feasible,
-        meta={"n_ports": n, "precision": precision, "use_pallas": use_pallas,
-              "use_kernel": bool(use_kernel)},
+        meta={"n_ports": n, "precision": precision, "use_pallas": use_pallas},
     )
 
 
@@ -332,15 +287,17 @@ def run_surrogate_batched(
     precision: str = "float64",
     quantiles: Sequence[float] = DEFAULT_QUANTILES,
     mesh=None,
-    use_kernel=False,
 ) -> BatchedSurrogateResult:
     """Evaluate a whole candidate batch against one shared trace.
 
-    ``mesh`` is an optional ``repro.launch.mesh.MeshSpec`` (or anything its
-    ``coerce`` accepts): when it names more than one shard the candidate
-    axis is padded to the mesh extent and the scan runs under ``shard_map``
-    across the device mesh — bit-identical to the serial path, which remains
-    the byte-identical default (``mesh=None``).
+    The candidate axis of the device call is padded to
+    ``repro.launch.mesh.bucket_size`` rows (replicas of row 0, stripped
+    before anything is reduced), so every real row is bit-identical to an
+    unpadded call.  ``mesh`` is an optional ``repro.launch.mesh.MeshSpec``
+    (or anything its ``coerce`` accepts): when it names more than one shard
+    the bucket is also a multiple of the shard count and the scan runs under
+    ``shard_map`` across the device mesh — bit-identical to the
+    single-device default (``mesh=None``).
 
     ``bound`` is one ``BoundProtocol`` shared by the batch, or — for the
     protocol/architecture co-design DSE — a per-candidate sequence (index-
@@ -356,17 +313,11 @@ def run_surrogate_batched(
     for the TPU; ``interpret=True`` runs it in the Pallas interpreter
     instead (how the CPU tests validate it).
 
-    ``use_kernel`` (``"auto"``/``"on"``/``"off"`` or a bool) switches the
-    exact occupancy count to the segmented flat-searchsorted kernel
-    (``repro.kernels.netsim.segmented_occupancy``) — bit-identical integer
-    counts, one pass over the whole batch instead of one per candidate.
-
     Memory: the result holds per-candidate sample arrays ([B, m] latencies,
     occupancy and departure times — stage 3 consumes the samples), so host
     memory scales as O(B·m); at ~1e5-packet traces budget ~2.5 MB/candidate
     and chunk very large sweeps into multiple calls.
     """
-    use_kernel = resolve_use_kernel(use_kernel)
     if use_pallas and precision == "float64":
         # the Pallas kernel is float32 by design (slack formulation); honour
         # that in the dtype, the meta, and the skipped enable_x64 — a silent
@@ -403,13 +354,11 @@ def run_surrogate_batched(
         groups.setdefault(a.n_ports, []).append(i)
     if len(groups) == 1:
         return _run_group(archs, bounds, trace, hw, use_pallas, interpret,
-                          precision, quantiles, mesh_spec=mesh,
-                          use_kernel=use_kernel)
+                          precision, quantiles, mesh_spec=mesh)
 
     parts = {n: _run_group([archs[i] for i in idx], [bounds[i] for i in idx],
                            trace, [hw[i] for i in idx], use_pallas, interpret,
-                           precision, quantiles, mesh_spec=mesh,
-                           use_kernel=use_kernel)
+                           precision, quantiles, mesh_spec=mesh)
              for n, idx in groups.items()}
     # stitch [B, m] arrays back in input order (m is shared: one trace)
     first = next(iter(parts.values()))
@@ -422,8 +371,7 @@ def run_surrogate_batched(
         q_occupancy=np.empty((len(archs),) + first.q_occupancy.shape[1:], np.int64),
         dep_end_s=np.empty((len(archs),) + first.dep_end_s.shape[1:]),
         t_s=first.t_s, line_rate_feasible=np.empty(len(archs), bool),
-        meta={"precision": precision, "use_pallas": use_pallas,
-              "use_kernel": bool(use_kernel)})
+        meta={"precision": precision, "use_pallas": use_pallas})
     for n, idx in groups.items():
         part = parts[n]
         for row, i in enumerate(idx):

@@ -15,7 +15,6 @@ so engines are swappable pipeline stages (a batched variant is either native
       0   analytic           closed-form resource/timing model       ~µs
       1   surrogate          event-driven transaction model          ~ms
       2   batched_surrogate  one jitted contention scan, B at once   ~ms/batch
-      2   batched_surrogate[kernel]  + segmented occupancy kernel    ~ms/batch
       3   netsim             finite buffers, drops, retransmission   ~100ms
       3   batched_netsim     the same model, one jitted scan         ~ms/cand
       3   batched_netsim[kernel]  segmented fixed-point kernel       ~µs/cand
@@ -171,33 +170,16 @@ def _surrogate_evaluate(arch, bound, trace, *, hw=None, back_annotation=False,
 
 
 def _batched_surrogate_batch(archs, bound, trace, *, hw=None,
-                             back_annotation=False, i_burst=1.0, mesh=None,
-                             use_kernel=False):
+                             back_annotation=False, i_burst=1.0, mesh=None):
     res = run_surrogate_batched(list(archs), bound, trace, hw=hw,
                                 back_annotation=back_annotation,
-                                i_burst=i_burst, mesh=mesh,
-                                use_kernel=use_kernel)
+                                i_burst=i_burst, mesh=mesh)
     return [_surrogate_to_verify(sr) for sr in res.results()]
 
 
 def _batched_surrogate_evaluate(arch, bound, trace, *, hw=None,
                                 back_annotation=False, i_burst=1.0):
     return _batched_surrogate_batch(
-        [arch], bound, trace, hw=[hw] if hw is not None else None,
-        back_annotation=back_annotation, i_burst=i_burst)[0]
-
-
-def _batched_surrogate_kernel_batch(archs, bound, trace, *, hw=None,
-                                    back_annotation=False, i_burst=1.0,
-                                    mesh=None):
-    return _batched_surrogate_batch(
-        archs, bound, trace, hw=hw, back_annotation=back_annotation,
-        i_burst=i_burst, mesh=mesh, use_kernel=True)
-
-
-def _batched_surrogate_kernel_evaluate(arch, bound, trace, *, hw=None,
-                                       back_annotation=False, i_burst=1.0):
-    return _batched_surrogate_kernel_batch(
         [arch], bound, trace, hw=[hw] if hw is not None else None,
         back_annotation=back_annotation, i_burst=i_burst)[0]
 
@@ -273,10 +255,6 @@ register_engine(
     "batched_surrogate", 2, _batched_surrogate_evaluate,
     _batched_surrogate_batch,
     doc="the transaction model as one jitted contention scan over the batch")
-register_engine(
-    "batched_surrogate[kernel]", 2, _batched_surrogate_kernel_evaluate,
-    _batched_surrogate_kernel_batch,
-    doc="rung 2 with the segmented occupancy kernel (bit-identical counts)")
 register_engine(
     "netsim", 3, _netsim_evaluate,
     doc="finite-buffer event-driven verifier (drops, retransmission)")

@@ -356,7 +356,7 @@ class SwitchDSEProblem(DSEProblem):
                 self.trace,
                 back_annotation=self.back_annotation,
                 i_burst=self.features.i_burst,
-                mesh=self.mesh_spec, use_kernel=self.use_kernel).results()
+                mesh=self.mesh_spec).results()
 
     # ------------------------------------------------------------- stage 3
     def size_buffers(self, c, q_occupancy: np.ndarray, eps: float):

@@ -397,7 +397,8 @@ def test_retrace_guard_single_device():
 
     bound = bind(compressed_protocol(addr_bits=4, length_bits=6),
                  flit_bits=256)
-    cands = enumerate_candidates(ArchRequest(n_ports=8, addr_bits=4))[:3]
+    wide = enumerate_candidates(ArchRequest(n_ports=8, addr_bits=4))[:9]
+    cands = wide[:3]
     # a duration no other test uses -> a fresh event-count shape, so the
     # expectations hold regardless of what ran earlier in the suite
     tr = hft(seed=0, duration_s=6.7e-5)
@@ -407,8 +408,10 @@ def test_retrace_guard_single_device():
     assert g.deltas() == {"surrogate.engine": 1}
     with retrace_guard(expect=0):
         run_surrogate_batched(cands, bound, tr, back_annotation=False)
-    with retrace_guard(expect=1):        # new batch size -> exactly one more
+    with retrace_guard(expect=0):        # same bucket width (8 rows)
         run_surrogate_batched(cands[:2], bound, tr, back_annotation=False)
+    with retrace_guard(expect=1):        # next bucket (16) -> exactly one more
+        run_surrogate_batched(wide, bound, tr, back_annotation=False)
 
     with retrace_guard(expect=1) as g:
         run_netsim_batched(cands, bound, tr, back_annotation=False)

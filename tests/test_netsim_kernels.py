@@ -20,7 +20,8 @@ from repro.core import (ArchRequest, ForwardTableKind, SLA, SchedulerKind,
                         SwitchArch, VOQKind, bind, compressed_protocol,
                         enumerate_candidates)
 from repro.kernels import netsim as kn
-from repro.sim import run_netsim, run_netsim_batched, run_surrogate_batched
+from repro.sim import (run_netsim, run_netsim_batched, run_surrogate,
+                       run_surrogate_batched)
 from repro.sim import timeline as tlmod
 from repro.sim.switch_problem import SwitchDSEProblem
 from repro.traces import datacenter, hft
@@ -137,7 +138,7 @@ def test_empty_trace_kernel():
     for v in vk:
         assert v.drop_rate == 0.0 and math.isinf(v.p99_latency_ns)
     sk = run_surrogate_batched(_sized_candidates()[:3], BOUND, empty,
-                               back_annotation=False, use_kernel=True)
+                               back_annotation=False)
     assert sk.q_occupancy.shape == (3, 0)
 
 
@@ -183,22 +184,119 @@ def test_duplicate_rows_fan_out_with_fresh_meta():
 # stage-2: segmented occupancy + lean replay oracles
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("workload", ["hft", "datacenter"])
+def _serial_occupancy(t, qid, dep):
+    """The serial engine's per-queue count: one ``searchsorted`` per queue
+    and row over that queue's own departures."""
+    occ = np.empty(dep.shape, np.int64)
+    for q in np.unique(qid):
+        sel = np.nonzero(qid == q)[0]
+        for b in range(dep.shape[0]):
+            occ[b, sel] = np.arange(sel.size) - np.searchsorted(
+                dep[b, sel], t[sel], side="right")
+    return occ
+
+
+@pytest.mark.parametrize("workload", ["hft", "datacenter", "one_chain"])
 def test_stage2_kernel_occupancy_bitwise(workload):
-    tr = _traces()[workload]
+    """The one occupancy count equals the serial engine's, packet by packet,
+    through the batched engine; shared-buffer occupancy too."""
+    if workload == "one_chain":
+        m = 96
+        tr = Trace("onechain", np.arange(m) * 3e-8, np.zeros(m, np.int32),
+                   np.ones(m, np.int32), np.full(m, 300, np.int64), 8,
+                   link_gbps=10.0)
+    else:
+        tr = _traces()[workload]
     cands = enumerate_candidates(ArchRequest(n_ports=8, addr_bits=4))[:10]
-    sk = run_surrogate_batched(cands, BOUND, tr, back_annotation=False,
-                               use_kernel=True)
-    so = run_surrogate_batched(cands, BOUND, tr, back_annotation=False,
-                               use_kernel=False)
-    np.testing.assert_array_equal(sk.q_occupancy, so.q_occupancy)
-    np.testing.assert_array_equal(sk.latency_ns, so.latency_ns)
-    np.testing.assert_array_equal(sk.dep_end_s, so.dep_end_s)
-    for rk, ro in zip(sk.results(), so.results()):
-        a, b = rk.meta["shared_occupancy"], ro.meta["shared_occupancy"]
-        assert (a is None) == (b is None)
-        if a is not None:
-            np.testing.assert_array_equal(a, b)
+    sk = run_surrogate_batched(cands, BOUND, tr, back_annotation=False)
+    tl = tlmod.stage2_timeline(tr, 8)
+    np.testing.assert_array_equal(
+        sk.q_occupancy, _serial_occupancy(tl.t, tl.qid, sk.dep_end_s))
+    for a, rk in zip(cands, sk.results()):
+        ro = run_surrogate(a, BOUND, tr, back_annotation=False)
+        np.testing.assert_array_equal(rk.q_occupancy, ro.q_occupancy)
+        x, y = rk.meta["shared_occupancy"], ro.meta["shared_occupancy"]
+        assert (x is None) == (y is None)
+        if x is not None:
+            np.testing.assert_array_equal(x, y)
+
+
+#: gaps after an arrival at which a departure of the same queue lands
+NEAR_TIES = (0.0, 1e-15, 1e-12, 1e-10)
+
+
+@pytest.mark.parametrize("gap", NEAR_TIES)
+def test_occupancy_exact_at_near_ties(gap):
+    """A departure 0, 1e-15, 1e-12 or 1e-10 s after an arrival of its own
+    queue, in the chain that ends the permuted timeline (positions near
+    m = 100,000) and in each of B = 40 rows, over a 0.16 s trace: a key that
+    adds the time to a row or chain offset in float64 has a spacing of
+    about B x m x span x 2^-52 = 1.4e-10 s there and miscounts these.  The
+    count equals the per-queue serial count, packet for packet."""
+    rng = np.random.default_rng(7)
+    m, b_n, n_q = 100_000, 40, 64
+    t = np.cumsum(rng.uniform(0.5e-6, 2.7e-6, m))
+    t -= t[0]
+    qid = rng.integers(0, n_q, m)
+    dep = np.empty((b_n, m))
+    for q in range(n_q):
+        sel = np.nonzero(qid == q)[0]
+        # FIFO within a queue, each departure after its own arrival
+        dep[:, sel] = np.maximum.accumulate(
+            t[sel] + rng.uniform(1e-7, 5e-6, (b_n, sel.size)), axis=1)
+    # row b: the last queue's member before its (b+1)-th last arrival
+    # departs ``gap`` after that arrival; later members depart later still
+    members = np.nonzero(qid == n_q - 1)[0]
+    for b in range(b_n):
+        k = members[-1 - b]
+        j = members[-2 - b]
+        assert dep[b, members[:-2 - b]].max() < t[k]
+        dep[b, j] = t[k] + gap
+    chain = kn.build_chain_index(qid)
+    assert chain.seg_start[chain.inv[members[-1]]] > 0.98 * m
+    got = kn.segmented_occupancy(t, dep, chain)
+    want = _serial_occupancy(t, qid, dep)
+    np.testing.assert_array_equal(got, want)
+    # at an exact tie the departure has happened (the serial side="right")
+    for b in range(b_n):
+        k = members[-1 - b]
+        assert want[b, k] == (0 if gap == 0.0 else 1)
+
+
+def _all_stages(cands, tr):
+    """Stage 2, and stage 4 on both engines, over ``cands`` sized at
+    depths 2 (drops, so later rounds replay) and 64."""
+    sized = [a.with_depth((2, 64)[i % 2]) for i, a in enumerate(cands)]
+    return (run_surrogate_batched(cands, BOUND, tr, back_annotation=False),
+            run_netsim_batched(sized, BOUND, tr, back_annotation=False,
+                               use_kernel=True),
+            run_netsim_batched(sized, BOUND, tr, back_annotation=False,
+                               use_kernel=False))
+
+
+@pytest.mark.parametrize("width", [1, 9, 33])
+def test_pad_rows_leave_real_rows_bit_identical(width, monkeypatch):
+    """Every device call pads its candidate axis to a bucket width (1 -> 8,
+    9 -> 16, 33 -> 48); each real row's outputs are bit for bit those of
+    the same calls made at the exact width."""
+    import repro.launch.mesh as mesh_mod
+
+    assert mesh_mod.bucket_size(width) > width
+    tr = hft(seed=0).head(512)
+    base = enumerate_candidates(ArchRequest(n_ports=8, addr_bits=4))
+    cands = [base[i % len(base)] for i in range(width)]
+    padded = _all_stages(cands, tr)
+    monkeypatch.setattr(mesh_mod, "bucket_pad", lambda a, k=1, axis=0: a)
+    exact = _all_stages(cands, tr)
+    (s_p, vk_p, vo_p), (s_e, vk_e, vo_e) = padded, exact
+    for f in ("dep_end_s", "latency_ns", "quantiles", "throughput_gbps"):
+        got, want = getattr(s_p, f), getattr(s_e, f)
+        np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))
+    np.testing.assert_array_equal(s_p.q_occupancy, s_e.q_occupancy)
+    if width > 1:
+        assert any(v.drop_rate > 0 for v in vk_e)
+    _assert_results_identical(vk_p, vk_e)
+    _assert_results_identical(vo_p, vo_e)
 
 
 @pytest.mark.parametrize("n_ports,b_n,m,seed,horizon", [
@@ -366,10 +464,8 @@ def test_use_kernel_fidelity_json_roundtrip():
 def test_kernel_rungs_registered():
     from repro.sim.engines import get_engine
 
-    for name, rung in (("batched_surrogate[kernel]", 2),
-                       ("batched_netsim[kernel]", 3)):
-        spec = get_engine(name)
-        assert spec.rung == rung and spec.batched
+    spec = get_engine("batched_netsim[kernel]")
+    assert spec.rung == 3 and spec.batched
     tr = hft(seed=0).head(256)
     cands = _sized_candidates()[:3]
     vk = get_engine("batched_netsim[kernel]").evaluate_batch(
@@ -516,9 +612,8 @@ for d in (2, 8):
         np.testing.assert_array_equal(vb.meta["latency_ns"],
                                       vr.meta["latency_ns"])
     s = run_surrogate_batched(cands, BOUND, tr, back_annotation=False,
-                              use_kernel=True, mesh=MeshSpec(devices=d))
-    s0 = run_surrogate_batched(cands, BOUND, tr, back_annotation=False,
-                               use_kernel=True)
+                              mesh=MeshSpec(devices=d))
+    s0 = run_surrogate_batched(cands, BOUND, tr, back_annotation=False)
     np.testing.assert_array_equal(s0.q_occupancy, s.q_occupancy)
     np.testing.assert_array_equal(s0.latency_ns, s.latency_ns)
     print("devices", d, "kernel bit-identical OK")

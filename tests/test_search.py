@@ -376,3 +376,35 @@ def test_error_messages_name_problem_and_shapes():
     sized = [(c, prob.resources(c)) for c in cands]
     with pytest.raises(ValueError, match=r"Broken\.verify_batch.*\[3\].*\[4\]"):
         stage4_verify(prob, sized, SLA_HFT)
+
+
+def test_search_meets_only_bucket_widths(monkeypatch):
+    """A short NSGA-II exploration asks stage 2 and stage 4 for many widths;
+    every device call pads them to ``bucket_size``, so the stage-2 and
+    stage-4 programs see at most the five widths up to 48."""
+    import repro.kernels.netsim.ops as ops
+    import repro.sim.batched_surrogate as bs
+    from repro.api import registry, run_scenario
+    from repro.launch.mesh import bucket_size
+
+    seen = {"stage2": [], "stage4": []}
+
+    def spy(fn, stage, axis):
+        def call(*args, **kw):
+            seen[stage].append(args[3].shape[axis])
+            return fn(*args, **kw)
+        return call
+
+    monkeypatch.setattr(bs, "_engine", spy(bs._engine, "stage2", 0))
+    monkeypatch.setattr(ops, "_round1", spy(ops._round1, "stage4", 1))
+    monkeypatch.setattr(ops, "_gated_replay",
+                        spy(ops._gated_replay, "stage4", 0))
+    s = registry["hft"].override(
+        back_annotation=False, top_k=8, trace_params={"duration_s": 8e-5},
+        search=SearchSpec(population=24, generations=4, seed=3))
+    report = run_scenario(s)
+    assert report.stage4_candidates > 0
+    allowed = {bucket_size(n) for n in range(1, 49)}
+    assert allowed == {8, 16, 24, 32, 48}
+    for stage, widths in seen.items():
+        assert widths and set(widths) <= allowed, (stage, widths)

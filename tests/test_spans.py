@@ -32,6 +32,7 @@ EXPLORE_TREE = {
     "spac.stage2.prepare": "spac.stage2",
     "spac.stage2.scan": "spac.stage2",
     "spac.stage2.reduce": "spac.stage2",
+    "spac.stage2.occupancy": "spac.stage2.reduce",
     "spac.screen": "spac.explore",
     "spac.stage3": "spac.explore",
     "spac.stage4": "spac.explore",
@@ -124,6 +125,44 @@ def test_device_calls_note_their_sweeps(explored, name):
     assert rec.attrs["scan_fallback"] == 0
 
 
+@pytest.mark.parametrize("name,parent", [
+    ("spac.stage2.scan", "spac.stage2"), ("spac.stage4.round1", "spac.stage4")])
+def test_device_calls_note_their_pad_rows(explored, name, parent):
+    from repro.launch.mesh import bucket_size
+
+    report, recs, _ = explored
+    rec = _one(recs, name)
+    rows = (_one(recs, parent).attrs["unique_rows"] if parent == "spac.stage4"
+            else _one(recs, parent).attrs["rows"])
+    assert rec.attrs["pad_rows"] == bucket_size(rows) - rows
+
+
+def test_occupancy_span_counts_rows_and_events(explored):
+    report, recs, _ = explored
+    occ, s2 = _one(recs, "spac.stage2.occupancy"), _one(recs, "spac.stage2")
+    assert occ.attrs == {"rows": s2.attrs["rows"],
+                         "events": len(report.problem.trace)}
+
+
+def test_replay_notes_pad_rows(tmp_path):
+    """Later fixed-point rounds replay only the dropping rows, padded."""
+    from repro.core import (ArchRequest, bind, compressed_protocol,
+                            enumerate_candidates)
+    from repro.sim import run_netsim_batched
+    from repro.traces import hft
+
+    bound = bind(compressed_protocol(addr_bits=4, length_bits=6),
+                 flit_bits=256)
+    cands = [a.with_depth(2) for a in
+             enumerate_candidates(ArchRequest(n_ports=8, addr_bits=4))[:3]]
+    vs, recs = _recorded(tmp_path, lambda: run_netsim_batched(
+        cands, bound, hft(seed=0), back_annotation=False, use_kernel=True))
+    assert any(v.drop_rate > 0 for v in vs)
+    replays = [r for r in recs if r.name == "spac.stage4.replay"]
+    # each round replays 1..3 dropping rows, padded to the bucket of 8
+    assert replays and all(r.attrs["pad_rows"] in {5, 6, 7} for r in replays)
+
+
 def test_profiler_host_plane_holds_the_spans(explored):
     _, recs, logdir = explored
     path = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
@@ -153,6 +192,18 @@ def test_search_nests_ask_tell_and_evaluation(tmp_path):
         assert found and all(by_id[r.parent] is search for r in found)
     asks = [r for r in recs if r.name == "spac.search.ask"]
     assert [r.attrs["generation"] for r in asks] == list(range(len(asks)))
+
+
+def test_search_notes_generations_and_evaluated_rows(tmp_path):
+    s = registry["hft"].override(
+        back_annotation=False, top_k=2, trace_params={"duration_s": 8e-5},
+        search=SearchSpec(population=8, generations=3, seed=4))
+    run_scenario(s)
+    _, recs = _recorded(tmp_path, lambda: run_scenario(s))
+    search = _one(recs, "spac.search")
+    assert search.attrs["generations"] == 3
+    rows = sum(r.attrs["rows"] for r in recs if r.name == "spac.stage2")
+    assert search.attrs["evaluated_rows"] == rows > 0
 
 
 def test_served_requests_are_roots_their_chunks_name(tmp_path):
