@@ -96,9 +96,22 @@ class SurrogateResult:
     latency_ns: np.ndarray        # per-packet latency samples
     throughput_gbps: float = 0.0
     meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: latency percentiles the producer already took, keyed by float q
+    #: (the batched engine carries its row of ``quantiles``); empty when
+    #: the producer carries none or the trace is empty
+    quantiles: Dict[float, float] = dataclasses.field(default_factory=dict)
 
     def p(self, q: float) -> float:
-        return float(np.percentile(self.latency_ns, q)) if self.latency_ns.size else math.inf
+        """Latency percentile ``q``: the carried value when there is one,
+        else a percentile over every sample (noted as a miss)."""
+        note(quantile_reads=1)
+        carried = self.quantiles.get(float(q))
+        if carried is not None:
+            return carried
+        if not self.latency_ns.size:
+            return math.inf
+        note(quantile_misses=1)
+        return float(np.percentile(self.latency_ns, q))
 
 
 @dataclasses.dataclass

@@ -152,6 +152,12 @@ class BatchedSurrogateResult:
             # candidate inside the loop below
             sorted_dep = np.sort(self.dep_end_s[shared_rows], axis=1)
             sorted_of = {b: sorted_dep[i] for i, b in enumerate(shared_rows)}
+        # each row carries the quantiles stage 2 took for the whole batch,
+        # so ``p(q)`` reads them instead of a new percentile over the row;
+        # an empty trace carries none (its quantile rows are zeros, and
+        # ``p`` must stay inf)
+        qs = [float(q) for q in self.quantile_qs]
+        rows = self.quantiles.tolist() if self.t_s.size else None
         for b, (arch, hw) in enumerate(zip(self.archs, self.hw)):
             if arch.voq is VOQKind.SHARED:
                 m = self.t_s.size
@@ -171,6 +177,7 @@ class BatchedSurrogateResult:
                     "line_rate_feasible": bool(self.line_rate_feasible[b]),
                     "batched": True,
                 },
+                quantiles=dict(zip(qs, rows[b])) if rows else {},
             ))
         return out
 
