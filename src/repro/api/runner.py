@@ -7,30 +7,28 @@ materialises the trace, instantiates the domain's ``DSEProblem`` and runs
 Algorithm 1 with the scenario's SLA/budget/fidelity.  The legacy wrappers
 remain as thin compatibility shims over the same machinery.
 
-``run_campaign`` exploits the staged DSE (``repro.core.dse``): it prunes
-every scenario (stage 1), fans *all* scenarios' surviving candidates through
-the batched surrogate engine (stage 2), sizes each scenario's survivors
-(stage 3), then fans *all* scenarios' sized candidates through the batched
-stage-4 verifier — at both batched stages, scenarios that share a trace and
-a bound protocol share one jitted call, and every scenario reuses a cached
-trace + feature analysis.  The campaign report carries aggregate stage-2
-*and* stage-4 throughput (candidates/sec across the whole campaign).
+Both entry points are clients of ``repro.core.dse.IncrementalDSE``, the one
+code that sequences the stages: they drain its stage-2 rows, advance it,
+drain its stage-4 rows and advance it again (``repro.core.dse.drain``).
+``run_campaign`` builds one machine per scenario and drains them together:
+scenarios that share a trace and a bound protocol share one jitted call at
+both batched stages, and every scenario reuses a cached trace + feature
+analysis.  The campaign report carries aggregate stage-2 *and* stage-4
+throughput (candidates/sec across the whole campaign).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
-import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 from repro.analysis.spans import note, span
 from repro.core.binding import BoundProtocol, bind
-from repro.core.dse import (DSEProblem, DSEResult, ResourceBudget, SLA,
-                            StageLog, SurrogateResult, VerifyResult,
-                            finalize_result, stage1_static, stage2_screen,
-                            stage3_size, stage4_verify)
-from repro.core.search import SearchDriver, run_search
+from repro.core.dse import (DSEProblem, DSEResult, IncrementalDSE,
+                            ResourceBudget, SLA, VerifyResult, drain,
+                            search_checkpoint_dir)
 
 from .registry import registry
 from .scenario import MeshSpec, Scenario
@@ -248,6 +246,18 @@ class ScenarioReport:
     stage4_candidates: int = 0
     stage4_time_s: float = 0.0
 
+    @classmethod
+    def of(cls, scenario: Scenario, machine: IncrementalDSE,
+           wall_time_s: float) -> "ScenarioReport":
+        """The report of a finished machine: its result, candidate counts
+        and stage times."""
+        return cls(scenario=scenario, result=machine.result,
+                   problem=machine.problem, wall_time_s=wall_time_s,
+                   stage2_candidates=machine.stage2_candidates,
+                   stage2_time_s=machine.stage2_time_s,
+                   stage4_candidates=machine.stage4_candidates,
+                   stage4_time_s=machine.stage4_time_s)
+
     @property
     def best(self) -> Optional[Any]:
         return self.result.best
@@ -392,24 +402,16 @@ class CampaignReport:
 # execution
 # --------------------------------------------------------------------------
 
-def _search_checkpoint_dir(scenario: Scenario, *, campaign: bool = False) -> Optional[str]:
-    """Campaigns nest each scenario's search state under its own name so one
-    ``checkpoint_dir`` serves the whole sweep."""
-    spec = scenario.search
-    if spec is None or not spec.checkpoint_dir:
-        return None
-    return (os.path.join(spec.checkpoint_dir, scenario.name)
-            if campaign else spec.checkpoint_dir)
-
-
 def run_scenario(scenario: Union[Scenario, str], *, verbose: bool = False,
                  resume: bool = False, mesh=None) -> ScenarioReport:
     """One spec in, verified Pareto front out (the quickstart in one call).
 
-    Runs the same staged composition as ``run_dse`` (inlined only to time
-    the batched surrogate call); ``tests/test_api.py`` asserts the stage
-    logs and Pareto front stay identical to the legacy ``optimize_switch``
-    → ``run_dse`` path, so the two cannot silently diverge.
+    One ``IncrementalDSE`` drained as ``run_dse`` drains it;
+    ``tests/test_api.py`` asserts the stage logs and Pareto front stay
+    identical to the legacy ``optimize_switch`` → ``run_dse`` path, so the
+    two cannot silently diverge.  ``stage2_time_s`` is the stage-2 call's
+    seconds, or under a search the whole ``spac.search`` span's: the
+    machine's construction and every generation's ask, call and tell.
 
     With ``scenario.search`` set, stages 1-2 are replaced by the seeded
     generational NSGA-II engine (``repro.core.search``); the final archive
@@ -423,71 +425,25 @@ def run_scenario(scenario: Union[Scenario, str], *, verbose: bool = False,
     """
     if isinstance(scenario, str):
         scenario = registry[scenario]
+    fid = scenario.fidelity
+    searching = scenario.search is not None
     with span("spac.explore", scenario=scenario.name) as root:
         problem, sla, budget = build_problem(scenario, mesh=mesh)
-        fid = scenario.fidelity
-        if scenario.search is not None:
-            with span("spac.search") as s2:
-                outcome = run_search(
-                    problem, scenario.search, sla, delta=fid.delta,
-                    checkpoint_dir=_search_checkpoint_dir(scenario),
-                    resume=resume)
-            valid, pre_logs = outcome.valid, [outcome.log]
-            stage2_cands = outcome.surrogate_rows
-            if verbose:
-                print(outcome.log)
-        else:
-            active, log1 = stage1_static(problem, delta=fid.delta)
-            if verbose:
-                print(log1)
-            with span("spac.stage2") as s2:
-                srs = problem.surrogate_batch(active)
-            valid, log2 = stage2_screen(problem, active, sla, surrogates=srs)
-            if verbose:
-                print(log2)
-            pre_logs = [log1, log2]
-            stage2_cands = len(active)
-        sized, n_explored = stage3_size(problem, valid, sla, budget,
-                                        top_k=fid.top_k)
-        with span("spac.stage4") as s4:
-            verifies = problem.verify_batch([a for a, _ in sized])
-        with span("spac.finalize"):
-            evaluated, best, best_v = stage4_verify(problem, sized, sla,
-                                                    verifies=verifies)
-            log3 = StageLog("stage3-sizing+verify", n_explored, len(sized))
-            if verbose:
-                print(log3)
-            result = finalize_result(problem, evaluated, best, best_v,
-                                     pre_logs + [log3])
-            return ScenarioReport(scenario=scenario, result=result,
-                                  problem=problem, wall_time_s=root.seconds,
-                                  stage2_candidates=stage2_cands,
-                                  stage2_time_s=s2.seconds,
-                                  stage4_candidates=len(sized),
-                                  stage4_time_s=s4.seconds)
-
-
-@dataclasses.dataclass
-class _Ctx:
-    scenario: Scenario
-    problem: DSEProblem
-    budget: ResourceBudget
-    shared_trace: bool
-    group_key: Optional[str]                 # None -> own surrogate_batch call
-    driver: Optional[SearchDriver] = None    # set iff scenario.search
-    active: List[Any] = dataclasses.field(default_factory=list)
-    log1: Optional[StageLog] = None
-    surrogates: List[SurrogateResult] = dataclasses.field(default_factory=list)
-    stage1_time_s: float = 0.0
-    stage2_time_s: float = 0.0               # this scenario's share of its batch
-    stage2_candidates: int = 0               # rows this scenario fanned out
-    # --- stages 2-screen + 3 (sizing), filled before the stage-4 fan-out
-    log2: Optional[StageLog] = None
-    sized: List[Any] = dataclasses.field(default_factory=list)
-    n_explored: int = 0
-    stage3_time_s: float = 0.0
-    verifies: List[VerifyResult] = dataclasses.field(default_factory=list)
-    stage4_time_s: float = 0.0               # this scenario's share of its batch
+        stage2 = span("spac.search") if searching else contextlib.nullcontext()
+        with stage2 as s2:
+            m = IncrementalDSE(problem, sla, budget, delta=fid.delta,
+                               top_k=fid.top_k, search=scenario.search,
+                               resume=resume)
+            drain([m], "surrogate")
+        if searching:
+            m.stage2_time_s = s2.seconds
+        m.advance()
+        drain([m], "verify")
+        m.advance()
+        if verbose:
+            for lg in m.result.logs:
+                print(lg)
+        return ScenarioReport.of(scenario, m, root.seconds)
 
 
 def _switch_group_key(s: Scenario) -> str:
@@ -508,14 +464,12 @@ def _switch_group_key(s: Scenario) -> str:
     }, sort_keys=True)
 
 
-def _verify_group_key(ctx: _Ctx) -> str:
+def _verify_group_key(s: Scenario) -> str:
     """Scenarios share one batched stage-4 call iff this key matches: the
     stage-2 key plus the verify engine (sized candidates from two scenarios
     may ride one jitted netsim scan only if the same rung verifies both)."""
-    if ctx.group_key is None:
-        return None
-    return (ctx.group_key + "|" + ctx.scenario.fidelity.verify_engine
-            + "|" + ctx.scenario.fidelity.use_kernel)
+    return (_switch_group_key(s) + "|" + s.fidelity.verify_engine
+            + "|" + s.fidelity.use_kernel)
 
 
 def run_campaign(
@@ -528,16 +482,19 @@ def run_campaign(
 ) -> CampaignReport:
     """Run many scenarios with shared trace analysis and batched stage 2.
 
-    Per-scenario results are identical to ``run_scenario`` (candidates of the
-    batched engine are row-independent), so a campaign is never a fidelity
-    trade-off — only a throughput one.
+    One ``IncrementalDSE`` per scenario, drained together.  Per-scenario
+    results are identical to ``run_scenario`` (candidates of the batched
+    engine are row-independent), so a campaign is never a fidelity
+    trade-off — only a throughput one.  Each scenario's ``wall_time_s`` is
+    its machine's own host seconds plus its share of the batched calls.
 
     Scenarios carrying a ``search`` spec run their generational engines in
     *lockstep*: each round, every active engine's pending population joins
     its group's single batched surrogate call (groups share a trace + bound
-    protocol exactly as in exhaustive stage 2), so N searching scenarios
-    still cost one jitted call per group per generation.  ``resume``
-    continues each scenario's checkpointed search from
+    protocol exactly as in exhaustive stage 2, but search and exhaustive
+    rows never share a call), so N searching scenarios still cost one
+    jitted call per group per generation.  ``resume`` continues each
+    scenario's checkpointed search from
     ``search.checkpoint_dir/<scenario name>``.
 
     ``mesh`` (a ``MeshSpec`` / device count, winning over each scenario's
@@ -553,206 +510,63 @@ def run_campaign(
     with span("spac.campaign", scenarios=len(scns)) as root:
         # ---- build: share built traces + feature analysis across scenarios
         from repro.core.features import analyze
-        trace_cache: Dict[str, Tuple[Any, Any]] = {}
-        ctxs: List[_Ctx] = []
+        traces: Dict[str, Tuple[Any, Any]] = {}
+        built = []
+        shared = 0
         with span("spac.build"):
             for s in scns:
-                if s.domain == "switch":
-                    tkey = s.trace.key()
-                    shared = tkey in trace_cache
-                    if not shared:
-                        tr = s.trace.build()
-                        trace_cache[tkey] = (tr, analyze(tr))
-                    tr, feats = trace_cache[tkey]
-                    problem, _, budget = build_problem(
-                        s, trace=tr, features=feats, mesh=mesh)
-                    ctxs.append(_Ctx(s, problem, budget, shared,
-                                     _switch_group_key(s)))
-                else:
-                    problem, _, budget = build_problem(s, mesh=mesh)
-                    ctxs.append(_Ctx(s, problem, budget, False, None))
-
-        # ---- search engines: one driver per searching scenario
-        for ctx in ctxs:
-            s = ctx.scenario
-            if s.search is not None:
-                ctx.driver = SearchDriver(
-                    ctx.problem, s.search, s.sla, delta=s.fidelity.delta,
-                    checkpoint_dir=_search_checkpoint_dir(s, campaign=True),
-                    resume=resume)
-
-        # ---- stage 1 per scenario (search drivers do their own static pruning)
-        for ctx in ctxs:
-            if ctx.driver is not None:
-                continue
-            with span("spac.stage1") as s1:
-                ctx.active, ctx.log1 = stage1_static(
-                    ctx.problem, delta=ctx.scenario.fidelity.delta)
-            ctx.stage1_time_s = s1.seconds
-            if verbose:
-                print(f"[{ctx.scenario.name}] {ctx.log1}")
-
-        # ---- stage 2: fan every scenario's survivors through the batched engine;
-        # scenarios sharing (trace, bound, fidelity) share one call
-        groups: Dict[str, List[_Ctx]] = {}
-        order: List[str] = []
-        for i, ctx in enumerate(ctxs):
-            if ctx.driver is not None:
-                continue
-            key = ctx.group_key if ctx.group_key is not None else f"solo-{i}"
-            if key not in groups:
-                groups[key] = []
-                order.append(key)
-            groups[key].append(ctx)
-
-        total_cands = 0
-        stage2_time = 0.0
-        n_batches = 0
-        for key in order:
-            members = groups[key]
-            archs = [a for ctx in members for a in ctx.active]
-            srs: List[SurrogateResult] = []
-            elapsed = 0.0
-            if archs:
-                with span("spac.stage2") as s2:
-                    srs = members[0].problem.surrogate_batch(archs)
-                elapsed = s2.seconds
-                stage2_time += elapsed
-                n_batches += 1
-                total_cands += len(archs)
-            off = 0
-            for ctx in members:
-                ctx.surrogates = srs[off:off + len(ctx.active)]
-                # apportion the batched call's cost by candidate share
-                ctx.stage2_time_s = elapsed * len(ctx.active) / max(len(archs), 1)
-                ctx.stage2_candidates = len(ctx.active)
-                off += len(ctx.active)
-
-        # ---- generational lockstep for searching scenarios: each round, every
-        # active engine's pending population rides its group's one batched call
-        sgroups: Dict[str, List[_Ctx]] = {}
-        sorder: List[str] = []
-        for i, ctx in enumerate(ctxs):
-            if ctx.driver is None:
-                continue
-            key = (ctx.group_key if ctx.group_key is not None
-                   else f"solo-{i}") + "|search"
-            if key not in sgroups:
-                sgroups[key] = []
-                sorder.append(key)
-            sgroups[key].append(ctx)
-        while any(not ctx.driver.done for key in sorder for ctx in sgroups[key]):
-            for key in sorder:
-                members = [ctx for ctx in sgroups[key] if not ctx.driver.done]
-                if not members:
+                if s.domain != "switch":
+                    built.append(build_problem(s, mesh=mesh))
                     continue
-                asks = [ctx.driver.ask_candidates() for ctx in members]
-                cands = [c for a in asks for c in a]
-                elapsed = 0.0
-                srs = []
-                if cands:
-                    with span("spac.stage2") as s2:
-                        srs = members[0].problem.surrogate_batch(cands)
-                    elapsed = s2.seconds
-                    stage2_time += elapsed
-                    n_batches += 1
-                    total_cands += len(cands)
-                off = 0
-                for ctx, a in zip(members, asks):
-                    ctx.driver.tell_candidates(srs[off:off + len(a)])
-                    ctx.stage2_time_s += elapsed * len(a) / max(len(cands), 1)
-                    ctx.stage2_candidates += len(a)
-                    off += len(a)
+                tkey = s.trace.key()
+                shared += tkey in traces
+                if tkey not in traces:
+                    tr = s.trace.build()
+                    traces[tkey] = (tr, analyze(tr))
+                tr, feats = traces[tkey]
+                built.append(build_problem(s, trace=tr, features=feats,
+                                           mesh=mesh))
 
-        # ---- stage-2 screening (or search finalize) + stage-3 sizing
-        for ctx in ctxs:
-            s = ctx.scenario
-            with span("spac.screen") as sc:
-                if ctx.driver is not None:
-                    outcome = ctx.driver.finalize()
-                    valid, ctx.log2 = outcome.valid, outcome.log
-                    # match solo run_scenario accounting: finalize()'s archive
-                    # re-surrogation (resume path) counts as stage-2 fan-out
-                    ctx.stage2_candidates = outcome.surrogate_rows
-                else:
-                    valid, ctx.log2 = stage2_screen(
-                        ctx.problem, ctx.active, s.sla,
-                        surrogates=ctx.surrogates)
-            with span("spac.stage3") as s3:
-                ctx.sized, ctx.n_explored = stage3_size(
-                    ctx.problem, valid, s.sla, ctx.budget,
-                    top_k=s.fidelity.top_k)
-            ctx.stage3_time_s = sc.seconds + s3.seconds
-            if verbose:
-                print(f"[{s.name}] {ctx.log2}")
+        machines: List[IncrementalDSE] = []
+        keys: Dict[int, Tuple[str, str]] = {}   # id -> (stage-2, stage-4 key)
+        for i, (s, (problem, sla, budget)) in enumerate(zip(scns, built)):
+            m = IncrementalDSE(
+                problem, sla, budget, delta=s.fidelity.delta,
+                top_k=s.fidelity.top_k, search=s.search,
+                checkpoint_dir=search_checkpoint_dir(s.search, s.name),
+                resume=resume)
+            machines.append(m)
+            solo = s.domain != "switch"
+            keys[id(m)] = (
+                (f"solo-{i}" if solo else _switch_group_key(s))
+                + ("|search" if s.search is not None else ""),
+                f"solo-{i}" if solo else _verify_group_key(s))
 
-        # ---- stage 4: fan every scenario's sized survivors through the batched
-        # verifier; scenarios sharing (trace, bound, fidelity, engine) share one
-        # jitted call, exactly as stage 2 shares the surrogate scan
-        vgroups: Dict[str, List[_Ctx]] = {}
-        vorder: List[str] = []
-        for i, ctx in enumerate(ctxs):
-            key = _verify_group_key(ctx) or f"solo-{i}"
-            if key not in vgroups:
-                vgroups[key] = []
-                vorder.append(key)
-            vgroups[key].append(ctx)
+        calls2, rows2, secs2 = drain(machines, "surrogate",
+                                     lambda m: keys[id(m)][0])
+        for m in machines:
+            m.advance()
+        calls4, rows4, secs4 = drain(machines, "verify",
+                                     lambda m: keys[id(m)][1])
+        for m in machines:
+            m.advance()
 
-        total_verifies = 0
-        stage4_time = 0.0
-        n_vbatches = 0
-        for key in vorder:
-            members = vgroups[key]
-            cands = [a for ctx in members for a, _ in ctx.sized]
-            vs: List[VerifyResult] = []
-            elapsed = 0.0
-            if cands:
-                with span("spac.stage4") as s4:
-                    vs = members[0].problem.verify_batch(cands)
-                elapsed = s4.seconds
-                stage4_time += elapsed
-                n_vbatches += 1
-                total_verifies += len(cands)
-            off = 0
-            for ctx in members:
-                ctx.verifies = vs[off:off + len(ctx.sized)]
-                # apportion the batched call's cost by candidate share
-                ctx.stage4_time_s = elapsed * len(ctx.sized) / max(len(cands), 1)
-                off += len(ctx.sized)
-
-        # ---- assemble per-scenario results
         reports: List[ScenarioReport] = []
-        for ctx in ctxs:
-            s = ctx.scenario
-            with span("spac.finalize") as fin:
-                evaluated, best, best_v = stage4_verify(
-                    ctx.problem, ctx.sized, s.sla, verifies=ctx.verifies)
-                log3 = StageLog("stage3-sizing+verify", ctx.n_explored,
-                                len(ctx.sized))
-                result = finalize_result(
-                    ctx.problem, evaluated, best, best_v,
-                    [lg for lg in (ctx.log1, ctx.log2, log3) if lg is not None])
+        for s, m in zip(scns, machines):
             if verbose:
-                print(f"[{s.name}] {log3}")
-            reports.append(ScenarioReport(
-                scenario=s, result=result, problem=ctx.problem,
-                wall_time_s=(ctx.stage1_time_s + ctx.stage2_time_s
-                             + ctx.stage3_time_s + ctx.stage4_time_s
-                             + fin.seconds),
-                stage2_candidates=ctx.stage2_candidates,
-                stage2_time_s=ctx.stage2_time_s,
-                stage4_candidates=len(ctx.sized),
-                stage4_time_s=ctx.stage4_time_s))
-
+                for lg in m.result.logs:
+                    print(f"[{s.name}] {lg}")
+            reports.append(ScenarioReport.of(
+                s, m, m.host_time_s + m.stage2_time_s + m.stage4_time_s))
         return CampaignReport(
             name=name,
             reports=reports,
-            stage2_candidates=total_cands,
-            stage2_time_s=stage2_time,
-            stage2_batches=n_batches,
-            stage4_candidates=total_verifies,
-            stage4_time_s=stage4_time,
-            stage4_batches=n_vbatches,
-            shared_trace_scenarios=sum(c.shared_trace for c in ctxs),
+            stage2_candidates=rows2,
+            stage2_time_s=secs2,
+            stage2_batches=calls2,
+            stage4_candidates=rows4,
+            stage4_time_s=secs4,
+            stage4_batches=calls4,
+            shared_trace_scenarios=shared,
             wall_time_s=root.seconds,
         )

@@ -12,7 +12,10 @@ rows, padded by repeating the final row) fanned through the shared problem's
 batched engines, so the jitted call shapes never change as requests come and
 go: the first request per (trace, layout) pair traces the XLA executables,
 every later request reuses them.  Requests sharing a problem share one chunk
-(the campaign runner's cross-scenario batching, made continuous).
+(the campaign runner's cross-scenario batching, made continuous).  After
+the chunks, the tick advances every machine whose queue drained
+(``IncrementalDSE.advance``): the machine, not the engine, knows the order
+of the stages.
 
 Chunking and padding are exact, not approximate: both batch hooks are
 row-independent (the invariant ``run_campaign`` already relies on), so a
@@ -125,9 +128,6 @@ class ServeRequest:
     report: Optional[Dict[str, Any]] = None  # golden-format report dict
     error: Optional[str] = None
     machine: Optional[IncrementalDSE] = None
-    problem: Any = None
-    stage2_time_s: float = 0.0               # this request's share of chunks
-    stage4_time_s: float = 0.0
     root: Optional[span] = None              # spac.serve.request, submit..finish
 
     @property
@@ -257,14 +257,11 @@ class DSEServeEngine:
         return scenario.budget or _default_budget(scenario)
 
     def _start(self, req: ServeRequest) -> None:
-        from .runner import _search_checkpoint_dir
         fid = req.scenario.fidelity
         problem, sla, budget = self._problem(req.scenario)
-        req.problem = problem
         req.machine = IncrementalDSE(
             problem, sla, budget, delta=fid.delta, top_k=fid.top_k,
-            search=req.scenario.search,
-            checkpoint_dir=_search_checkpoint_dir(req.scenario))
+            search=req.scenario.search)
 
     # ------------------------------------------------------------------ tick
     def step(self) -> int:
@@ -301,7 +298,7 @@ class DSEServeEngine:
             m = req.machine
             if m is None or m.done or not m.pending:
                 continue
-            gkey = (id(req.problem), m.kind)
+            gkey = (id(m.problem), m.kind)
             if gkey not in groups:
                 groups[gkey] = []
                 order.append(gkey)
@@ -310,9 +307,12 @@ class DSEServeEngine:
         for gkey in order:
             self._run_chunk(gkey[1], groups[gkey])
 
-        # ---- retire finished machines
+        # ---- advance machines whose queue drained; retire finished ones
         for slot, _, req in list(self._slots.active_slots()):
-            if req.machine is not None and req.machine.done:
+            m = req.machine
+            while m is not None and not m.done and not m.pending:
+                m.advance()
+            if m is not None and m.done:
                 self._finalize(slot, req)
 
         # ---- resolve waiting twins: their original just finished (serve
@@ -358,7 +358,7 @@ class DSEServeEngine:
         fair share of each member's pending rows, pad to the fixed width by
         repeating the last row, evaluate, slice each member's results back."""
         width = self.batch_width if kind == "surrogate" else self.verify_width
-        problem = members[0].problem
+        problem = members[0].machine.problem
         pendings = [m.machine.pending for m in members]
         shares = _fair_shares([len(p) for p in pendings], width)
         take: List[Any] = []
@@ -383,9 +383,9 @@ class DSEServeEngine:
                 off += n
             share_s = elapsed * n / max(len(take), 1)
             if kind == "surrogate":
-                req.stage2_time_s += share_s
+                req.machine.stage2_time_s += share_s
             else:
-                req.stage4_time_s += share_s
+                req.machine.stage4_time_s += share_s
         if kind == "surrogate":
             self.stage2_time_s += elapsed
             self.counters["stage2_rows"] += len(take)
@@ -399,14 +399,9 @@ class DSEServeEngine:
 
     def _finalize(self, slot: int, req: ServeRequest) -> None:
         with span("spac.serve.finalize", rid=req.rid):
-            m = req.machine
-            report = ScenarioReport(
-                scenario=req.scenario, result=m.result, problem=req.problem,
-                wall_time_s=time.perf_counter() - req.admit_time_s,
-                stage2_candidates=m.stage2_candidates,
-                stage2_time_s=req.stage2_time_s,
-                stage4_candidates=m.stage4_candidates,
-                stage4_time_s=req.stage4_time_s)
+            report = ScenarioReport.of(
+                req.scenario, req.machine,
+                time.perf_counter() - req.admit_time_s)
             d = report.to_dict()
             self._reports[req.key] = d
             _evict(self._reports, _MAX_REPORTS)

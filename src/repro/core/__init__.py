@@ -32,7 +32,6 @@ from .dse import (
     stage1_static,
     stage2_screen,
     stage3_size,
-    stage3_verify,
     stage4_verify,
 )
 from .dsl import (
@@ -71,6 +70,5 @@ __all__ = [
     "compressed_protocol_space", "depth_for_drop_rate", "enumerate_candidates",
     "ethernet_ipv4_udp", "evaluate_space", "finalize_result", "hypervolume_2d",
     "is_dominated", "layout_key", "pareto_front", "run_dse", "run_search",
-    "stage1_static", "stage2_screen", "stage3_size", "stage3_verify",
-    "stage4_verify",
+    "stage1_static", "stage2_screen", "stage3_size", "stage4_verify",
 ]

@@ -20,7 +20,10 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence, Tuple
+import os
+import time
+from typing import (TYPE_CHECKING, Any, Callable, Dict, List, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
@@ -45,12 +48,13 @@ __all__ = [
     "stage1_static",
     "stage2_screen",
     "stage3_size",
-    "stage3_verify",
     "stage4_verify",
     "finalize_result",
     "check_index_aligned",
     "depth_for_drop_rate",
     "IncrementalDSE",
+    "drain",
+    "search_checkpoint_dir",
 ]
 
 
@@ -176,8 +180,8 @@ class DSEProblem:
         """Stage-4 fan-out hook: verify a whole sized-candidate batch at once.
 
         The mirror of ``surrogate_batch`` one rung up the fidelity ladder:
-        ``stage3_verify`` sizes *all* explored candidates first, then fans the
-        sized survivors through one call here.  Results must be index-aligned
+        stage 3 sizes *all* explored candidates first, then the sized
+        survivors fan through one call here.  Results must be index-aligned
         with ``cands``.  The default is the serial fallback (one ``verify``
         call per candidate); problems with a batched verifier override it —
         the switch problem runs the finite-buffer event simulator as one
@@ -308,11 +312,10 @@ def stage2_screen(
 ) -> Tuple[List[Tuple[Any, SurrogateResult]], StageLog]:
     """Stage 2: coarse-grained profiling + SLA screening.
 
-    ``surrogates`` lets a caller inject precomputed stage-2 results (index-
-    aligned with ``active``) — the campaign runner uses this to fan *several*
-    scenarios' candidates through one batched-engine call and hand each
-    scenario its slice back.  When absent, the problem's ``surrogate_batch``
-    hook runs (vectorised where the problem provides it, serial otherwise).
+    ``surrogates`` are the stage-2 results, index-aligned with ``active``:
+    ``IncrementalDSE`` passes the rows its clients evaluated, possibly in
+    calls shared with other machines' rows.  When absent, the problem's
+    ``surrogate_batch`` hook runs here.
     """
     active = list(active)
     srs = list(surrogates) if surrogates is not None else problem.surrogate_batch(active)
@@ -380,12 +383,12 @@ def stage4_verify(
     verifies: Optional[Sequence[VerifyResult]] = None,
 ) -> Tuple[List[Tuple[Any, VerifyResult, Dict[str, float], bool]],
            Optional[Any], Optional[VerifyResult]]:
-    """Stage 4: fan the sized survivors through one ``verify_batch`` call.
+    """Stage 4: rank the sized survivors on their verification results.
 
-    ``verifies`` lets a caller inject precomputed verification results
-    (index-aligned with ``sized``) — the campaign runner uses this to batch
-    stage 4 across scenarios sharing a (trace, bound protocol, engine)
-    exactly as it already batches stage 2.  The champion is escalated via
+    ``verifies`` are the verification results, index-aligned with
+    ``sized``: ``IncrementalDSE`` passes the rows its clients evaluated,
+    possibly in calls shared with other machines' rows.  When absent, the
+    problem's ``verify_batch`` hook runs here.  The champion is escalated via
     ``problem.escalate`` (a no-op by default)."""
     cands = [a for a, _ in sized]
     vs = list(verifies) if verifies is not None else problem.verify_batch(cands)
@@ -404,25 +407,6 @@ def stage4_verify(
         if esc is not None:
             best_v.meta["escalated"] = esc
     return evaluated, best, best_v
-
-
-def stage3_verify(
-    problem: DSEProblem,
-    valid: Sequence[Tuple[Any, SurrogateResult]],
-    sla: SLA,
-    budget: ResourceBudget,
-    *,
-    top_k: int = 8,
-    verifies: Optional[Sequence[VerifyResult]] = None,
-) -> Tuple[List[Tuple[Any, VerifyResult, Dict[str, float], bool]],
-           Optional[Any], Optional[VerifyResult], StageLog]:
-    """Stages 3+4 composed: size all explored candidates, then verify the
-    sized survivors in one batch (see ``stage3_size`` / ``stage4_verify``)."""
-    sized, n_explored = stage3_size(problem, valid, sla, budget, top_k=top_k)
-    evaluated, best, best_v = stage4_verify(problem, sized, sla,
-                                            verifies=verifies)
-    return evaluated, best, best_v, StageLog("stage3-sizing+verify",
-                                             n_explored, len(sized))
 
 
 def finalize_result(
@@ -453,9 +437,9 @@ def run_dse(
 ) -> DSEResult:
     """Algorithm 1: Progressive Constraint Satisfaction.
 
-    Composed from the staged functions above so callers that need to
-    interleave stages across problems (``repro.api.run_campaign`` batches
-    stage 2 across scenarios) reuse the exact same semantics.
+    One ``IncrementalDSE`` drained by ``drain``, the same four steps every
+    entry point runs (``repro.api.run_scenario``, ``run_campaign`` and the
+    serve engine batch the same machine's rows).
 
     ``search`` replaces the exhaustive stage-1/2 enumeration with the
     generational NSGA-II engine over ``problem.space()`` (seeded, resumable
@@ -473,74 +457,83 @@ def run_dse(
     if mesh is not None:
         from repro.launch.mesh import MeshSpec
         problem.mesh_spec = MeshSpec.coerce(mesh)
-    if search is not None:
-        from .search import run_search
-        outcome = run_search(problem, search, sla, delta=delta,
-                             checkpoint_dir=checkpoint_dir, resume=resume)
-        valid, logs = outcome.valid, [outcome.log]
-        if verbose:
-            print(outcome.log)
-    else:
-        active, log1 = stage1_static(problem, delta=delta)
-        if verbose:
-            print(log1)
-        valid, log2 = stage2_screen(problem, active, sla)
-        if verbose:
-            print(log2)
-        logs = [log1, log2]
-    evaluated, best, best_v, log3 = stage3_verify(problem, valid, sla, budget, top_k=top_k)
+    m = IncrementalDSE(problem, sla, budget, delta=delta, top_k=top_k,
+                       search=search, checkpoint_dir=checkpoint_dir,
+                       resume=resume)
+    drain([m], "surrogate")
+    m.advance()
+    drain([m], "verify")
+    m.advance()
     if verbose:
-        print(log3)
-    return finalize_result(problem, evaluated, best, best_v, logs + [log3])
+        for lg in m.result.logs:
+            print(lg)
+    return m.result
+
+
+def search_checkpoint_dir(search: Optional["SearchSpec"],
+                          nest: Optional[str] = None) -> Optional[str]:
+    """Where a search keeps its state: ``search.checkpoint_dir``, or, for a
+    campaign, a directory under it named ``nest`` (the scenario's name), so
+    one ``checkpoint_dir`` serves the whole sweep."""
+    if search is None or not search.checkpoint_dir:
+        return None
+    return (os.path.join(search.checkpoint_dir, nest) if nest
+            else search.checkpoint_dir)
 
 
 class IncrementalDSE:
-    """Algorithm 1 as a per-request state machine for an external batcher.
+    """Algorithm 1 for one problem, as a state machine its owner batches.
 
-    The staged functions above run one request's whole batch per call; the
-    serving engine (``repro.api.service``) instead multiplexes many
-    concurrent requests through *shared* fixed-width jitted calls, so it
-    needs each request's stage work exposed as "which candidates do you need
-    evaluated next, at which fidelity?".  An ``IncrementalDSE`` holds one
-    request's Algorithm-1 state:
+    This is the one place that knows the order of the stages.  Every entry
+    point (``run_dse``, ``repro.api.run_scenario``, ``run_campaign`` and the
+    serve engine) is a client that evaluates the machine's ``pending`` rows
+    through the problem's batch hooks, hands them back with ``feed()`` and
+    calls ``advance()`` once a queue has drained:
 
-    * stage 1 runs at construction (host-side, cheap);
-    * ``kind``/``pending`` expose the current evaluation queue —
-      ``"surrogate"`` rows until stage 2 drains, then ``"verify"`` rows;
-    * the owner evaluates any *prefix* of ``pending`` (batched together with
-      other requests' rows) and hands the index-aligned results to
-      ``feed()``; when a queue drains the machine advances — screen → size →
-      verify → ``result``.
+    * stage 1 runs at construction; ``kind`` is ``"surrogate"`` and
+      ``pending`` holds the stage-2 rows;
+    * ``feed()`` stores index-aligned results for any *prefix* of
+      ``pending``.  Under a ``SearchSpec``, ``pending`` is the current
+      generation's population (``repro.core.search.SearchDriver``), and a
+      fully fed generation is told to the engine, which asks the next one;
+      once the engine is done its ``finalize()`` runs inside ``feed()``, so
+      all search work stays stage-2 work;
+    * ``advance()`` on a drained stage-2 queue runs the screen and stage-3
+      sizing: ``kind`` becomes ``"verify"`` and ``pending`` holds the sized
+      rows.  On a drained stage-4 queue it ranks the verified rows,
+      escalates the champion and builds the front under ``spac.finalize``:
+      ``kind`` becomes ``"done"`` and ``result`` is set.
 
-    Feeding results chunk-at-a-time is exact because both batch hooks are
-    row-independent — the same invariant the campaign runner's
-    cross-scenario batching already relies on, so a served request's
-    ``DSEResult`` is identical to ``run_dse`` on the same problem.
-
-    With a ``SearchSpec``, stage 2 becomes the generational ask/tell loop
-    (``repro.core.search.SearchDriver``): ``pending`` is the current
-    generation's population, and a fully-fed generation advances the engine
-    exactly as the campaign's lockstep driver does.
+    Feeding results chunk at a time, or together with other machines' rows
+    in one call, is exact because both batch hooks are row-independent, so
+    every client gets the same ``DSEResult``.  ``stage2_time_s`` and
+    ``stage4_time_s`` hold this machine's share of the batched calls'
+    seconds (its share of their rows), ``host_time_s`` the seconds of its
+    own construction and ``advance()`` calls.
     """
 
     def __init__(self, problem: DSEProblem, sla: SLA, budget: ResourceBudget,
                  *, delta: float = 0.2, top_k: int = 8,
                  search: Optional["SearchSpec"] = None,
                  checkpoint_dir: Optional[str] = None, resume: bool = False):
+        t0 = time.perf_counter()
         self.problem = problem
         self.sla = sla
         self.budget = budget
         self.top_k = top_k
         self.kind = "surrogate"
-        self.stage2_candidates = 0      # rows this request fanned out
+        self.stage2_candidates = 0      # rows this machine fanned out
         self.stage4_candidates = 0      # sized rows verified
+        self.stage2_time_s = 0.0
+        self.stage4_time_s = 0.0
         self._logs: List[StageLog] = []
         self._pending: List[Any] = []
         self._fed: List[Any] = []
+        self._valid: Optional[List[Tuple[Any, SurrogateResult]]] = None
         self._sized: List[Tuple[Any, Dict[str, float]]] = []
         self._n_explored = 0
         self._result: Optional[DSEResult] = None
-        self._driver = None
+        self._driver = None             # the search, until it is finalized
         if search is not None:
             from .search import SearchDriver
             self._driver = SearchDriver(problem, search, sla, delta=delta,
@@ -552,8 +545,7 @@ class IncrementalDSE:
             self._logs.append(log1)
             self._pending = list(self._active)
             self.stage2_candidates = len(self._active)
-            if not self._pending:
-                self._finish_stage2()
+        self.host_time_s = time.perf_counter() - t0
 
     # -------------------------------------------------------------- queries
     @property
@@ -572,23 +564,29 @@ class IncrementalDSE:
         The owner may evaluate any prefix and ``feed()`` it back."""
         return list(self._pending)
 
-    # ------------------------------------------------------------- advance
+    # ---------------------------------------------------------- transitions
     def _ask(self) -> None:
-        """Generational mode: advance to the next non-empty population (an
-        ask can come back empty when every genome was pruned or answered
-        from the phenotype cache — tell the engine and move on)."""
+        """Search: move to the next non-empty population (an ask can come
+        back empty when every genome was pruned or answered from the
+        phenotype cache — tell the engine and move on); finalize the search
+        once the engine is done."""
         while not self._driver.done:
             cands = self._driver.ask_candidates()
             if cands:
                 self._pending = list(cands)
-                self._fed = []
                 return
             self._driver.tell_candidates([])
-        self._finish_stage2()
+        outcome = self._driver.finalize()
+        self._valid = outcome.valid
+        self._logs.append(outcome.log)
+        # finalize()'s archive re-surrogation (resume path) counts as
+        # stage-2 fan-out
+        self.stage2_candidates = outcome.surrogate_rows
+        self._driver = None
 
     def feed(self, results: Sequence[Any]) -> None:
         """Hand back index-aligned results for the first ``len(results)``
-        entries of ``pending``; drained queues advance the stage machine."""
+        entries of ``pending``."""
         if self.done:
             raise ValueError("IncrementalDSE is already finished")
         results = list(results)
@@ -598,42 +596,82 @@ class IncrementalDSE:
                 "pending candidates; feed at most the pending prefix")
         self._fed.extend(results)
         del self._pending[:len(results)]
-        if self._pending:
-            return
+        if not self._pending and self._driver is not None:
+            self._driver.tell_candidates(self._fed)
+            self._fed = []
+            self._ask()
+
+    def advance(self) -> None:
+        """Run the host stage that follows a drained queue: the screen and
+        stage 3 after stage 2, the ranking and the result after stage 4."""
+        if self.done or self._pending:
+            raise ValueError("advance() needs a drained queue of an "
+                             "unfinished IncrementalDSE")
+        t0 = time.perf_counter()
         if self.kind == "surrogate":
-            if self._driver is not None:
-                self._driver.tell_candidates(self._fed)
-                self._ask()
-            else:
-                self._finish_stage2()
+            if self._valid is None:
+                self._valid, log2 = stage2_screen(
+                    self.problem, self._active, self.sla,
+                    surrogates=self._fed)
+                self._logs.append(log2)
+            self._sized, self._n_explored = stage3_size(
+                self.problem, self._valid, self.sla, self.budget,
+                top_k=self.top_k)
+            self.kind = "verify"
+            self._pending = [a for a, _ in self._sized]
+            self._fed = []
+            self.stage4_candidates = len(self._sized)
         else:
-            self._finish()
+            with span("spac.finalize"):
+                evaluated, best, best_v = stage4_verify(
+                    self.problem, self._sized, self.sla, verifies=self._fed)
+                log3 = StageLog("stage3-sizing+verify", self._n_explored,
+                                len(self._sized))
+                self._result = finalize_result(
+                    self.problem, evaluated, best, best_v, self._logs + [log3])
+            self.kind = "done"
+        self.host_time_s += time.perf_counter() - t0
 
-    def _finish_stage2(self) -> None:
-        if self._driver is not None:
-            outcome = self._driver.finalize()
-            valid, log2 = outcome.valid, outcome.log
-            # finalize()'s archive re-surrogation (resume path) counts as
-            # stage-2 fan-out, matching run_scenario's accounting
-            self.stage2_candidates = outcome.surrogate_rows
-        else:
-            valid, log2 = stage2_screen(self.problem, self._active, self.sla,
-                                        surrogates=self._fed)
-        self._logs.append(log2)
-        self._sized, self._n_explored = stage3_size(
-            self.problem, valid, self.sla, self.budget, top_k=self.top_k)
-        self.kind = "verify"
-        self._pending = [a for a, _ in self._sized]
-        self._fed = []
-        self.stage4_candidates = len(self._sized)
-        if not self._pending:
-            self._finish()
 
-    def _finish(self) -> None:
-        evaluated, best, best_v = stage4_verify(self.problem, self._sized,
-                                                self.sla, verifies=self._fed)
-        log3 = StageLog("stage3-sizing+verify", self._n_explored,
-                        len(self._sized))
-        self._result = finalize_result(self.problem, evaluated, best, best_v,
-                                       self._logs + [log3])
-        self.kind = "done"
+def drain(machines: Sequence[IncrementalDSE], kind: str,
+          key: Callable[[IncrementalDSE], Any] = id) -> Tuple[int, int, float]:
+    """Evaluate every pending row of ``kind`` (``"surrogate"`` or
+    ``"verify"``) across ``machines``; returns (calls, rows, seconds).
+
+    Each round makes one ``surrogate_batch`` or ``verify_batch`` call (span
+    ``spac.stage2`` or ``spac.stage4``) per group key over the rows of the
+    machines that ``key`` puts in that group (by default each machine is
+    its own group), through the first member's problem, and feeds each
+    machine its slice back; rounds repeat while a search asks for further
+    generations.  Each call's seconds go to its members' ``stage2_time_s``
+    or ``stage4_time_s`` by their share of its rows."""
+    hook, name = (("surrogate_batch", "spac.stage2") if kind == "surrogate"
+                  else ("verify_batch", "spac.stage4"))
+    calls = rows = 0
+    seconds = 0.0
+    while True:
+        groups: Dict[Any, List[IncrementalDSE]] = {}
+        for m in machines:
+            if m.kind == kind and m._pending:
+                groups.setdefault(key(m), []).append(m)
+        if not groups:
+            return calls, rows, seconds
+        for members in groups.values():
+            parts = [m.pending for m in members]
+            batch = [c for p in parts for c in p]
+            problem = members[0].problem
+            with span(name) as sp:
+                out = list(getattr(problem, hook)(batch))
+            check_index_aligned(problem, out, batch, hook)
+            off = 0
+            for m, p in zip(members, parts):
+                share = sp.seconds * (len(p) / len(batch))
+                if kind == "surrogate":
+                    m.stage2_time_s += share
+                else:
+                    m.stage4_time_s += share
+                m.feed(out[off:off + len(p)])
+                off += len(p)
+            calls += 1
+            rows += len(batch)
+            seconds += sp.seconds

@@ -20,9 +20,11 @@ engine that rides the batched surrogate fan-out from PR 1-3:
     candidates, and checkpoints generation state via
     ``repro.checkpoint.store`` (population, archive, RNG state, generation).
   * Each generation's un-evaluated population fans through **one**
-    ``surrogate_batch`` call; the campaign runner drives several drivers in
-    generational lockstep so scenarios sharing a (trace, bound protocol)
-    share one jitted call per generation, exactly like exhaustive stage 2.
+    ``surrogate_batch`` call.  ``repro.core.dse.IncrementalDSE`` runs a
+    driver as its stage 2, and ``repro.core.dse.drain`` batches several
+    machines' generations together, so a campaign's scenarios sharing a
+    (trace, bound protocol) share one jitted call per generation, exactly
+    like exhaustive stage 2.
   * The final archive feeds the unchanged ``stage3_size``/``stage4_verify``
     ladder, so verification semantics are engine-independent.
 
@@ -560,9 +562,9 @@ class SearchDriver:
     decode to one micro-architecture); ``tell_candidates()`` maps the batched
     surrogate results back to genomes, folds the SLA into a constraint-
     violation scalar and advances the engine one generation, checkpointing
-    if a directory is configured.  The campaign runner drives several
-    instances in generational lockstep so every scenario's population rides
-    one batched call per generation.
+    if a directory is configured.  ``finalize()`` notes this run's
+    generations and priced rows on the enclosing span (``spac.search``).
+    ``IncrementalDSE`` drives one instance as its stage 2.
     """
 
     def __init__(self, problem, spec: SearchSpec, sla: SLA, *,
@@ -596,6 +598,7 @@ class SearchDriver:
                     f"{self.checkpoint_dir!r}; starting a fresh search",
                     RuntimeWarning, stacklevel=3)
         self.engine = engine if engine is not None else NSGA2Search(space, spec)
+        self._start_generation = self.engine.generation
         self._decoded: Dict[Genome, Any] = {}
         self._static_ok: Dict[Genome, bool] = {}
         self._sr: Dict[Any, SurrogateResult] = {}     # phenotype-level cache
@@ -699,6 +702,9 @@ class SearchDriver:
                 self._sr[c] = sr
             self.surrogate_rows += len(missing)
         valid = [(c, self._sr[c]) for c in cands]
+        # this run's generations and the candidate rows it priced
+        note(generations=eng.generation - self._start_generation,
+             evaluated_rows=self.surrogate_rows)
         hv = eng.hv_history[-1] if eng.hv_history else 0.0
         notes = [
             f"algorithm={self.spec.algorithm}",
@@ -757,11 +763,7 @@ def run_search(problem, spec: SearchSpec, sla: SLA, *, delta: float = 0.2,
             cands = driver.ask_candidates()
             srs = problem.surrogate_batch(cands)
             driver.tell_candidates(srs)
-        outcome = driver.finalize()
-        # this call's generations and the candidate rows it priced
-        note(generations=driver.engine.generation - start_gen,
-             evaluated_rows=driver.surrogate_rows)
-        return outcome
+        return driver.finalize()
 
 
 # --------------------------------------------------------------------------

@@ -11,7 +11,7 @@ from repro.api import (ProtocolSpec, Scenario, ScenarioRegistry, TraceSpec,
                        registry, run_campaign, run_scenario)
 from repro.api.cli import load_campaign_config, main, resolve_entry
 from repro.api.runner import build_bound
-from repro.api.scenario import CommModelSpec, Fidelity
+from repro.api.scenario import CommModelSpec, Fidelity, SearchSpec
 from repro.core import (AUTO, ArchRequest, ResourceBudget, SLA, SchedulerKind,
                         VOQKind, Field, bind, compressed_protocol)
 from repro.sim import optimize_switch
@@ -207,6 +207,37 @@ def test_run_campaign_shares_traces_and_batches_across_scenarios():
     # same spec -> same best either way; the relaxed SLA can only widen
     solo = run_scenario(base)
     assert campaign["hft"].best == solo.best
+
+
+@pytest.mark.parametrize("search", [
+    None, SearchSpec(population=8, generations=2, seed=3)],
+    ids=["exhaustive", "nsga2"])
+def test_every_driver_gives_one_result(search):
+    """run_dse, run_scenario, a one-scenario campaign and the serve engine
+    are clients of one state machine: same stage logs, front and champion."""
+    from repro.api import ScenarioReport
+    from repro.api.runner import build_problem
+    from repro.api.service import DSEServeEngine
+    from repro.core import run_dse
+
+    s = dataclasses.replace(_tiny("hft", duration_s=8e-5), search=search)
+    problem, sla, budget = build_problem(s)
+    by_dse = run_dse(problem, sla, budget, delta=s.fidelity.delta,
+                     top_k=s.fidelity.top_k, search=search)
+    eng = DSEServeEngine(slots=1)
+    req = eng.submit(s)
+    eng.run_until_drained()
+    got = {
+        "run_dse": ScenarioReport(s, by_dse, problem, 0.0).to_dict(),
+        "run_scenario": run_scenario(s).to_dict(),
+        "run_campaign": run_campaign([s]).reports[0].to_dict(),
+        "serve": req.report,
+    }
+    want = {k: got["run_scenario"][k]
+            for k in ("stages", "pareto", "best", "best_verify")}
+    assert want["best"] is not None and want["pareto"]
+    for driver, d in got.items():
+        assert {k: d[k] for k in want} == want, driver
 
 
 # ----------------------------------------------------------------------- CLI

@@ -194,6 +194,44 @@ def test_search_nests_ask_tell_and_evaluation(tmp_path):
     assert [r.attrs["generation"] for r in asks] == list(range(len(asks)))
 
 
+#: the same tree under NSGA-II: the search replaces stage 1, the stage-2
+#: call and the screen (``None``: not recorded at all)
+SEARCH_TREE = {
+    "spac.build": "spac.explore",
+    "spac.search": "spac.explore",
+    "spac.stage1": None,
+    "spac.screen": None,
+    "spac.stage3": "spac.explore",
+    "spac.stage4": "spac.explore",
+    "spac.finalize": "spac.explore",
+}
+
+
+@pytest.fixture(scope="module")
+def searched(tmp_path_factory):
+    s = registry["hft"].override(
+        back_annotation=False, top_k=2, trace_params={"duration_s": 8e-5},
+        search=SearchSpec(population=8, generations=2, seed=3))
+    run_scenario(s)                        # compile outside the traced run
+    return _recorded(tmp_path_factory.mktemp("search"),
+                     lambda: run_scenario(s))
+
+
+@pytest.mark.parametrize("name", sorted(SEARCH_TREE))
+def test_search_exploration_records_the_span_tree(searched, name):
+    _, recs = searched
+    want = SEARCH_TREE[name]
+    if want is None:
+        assert [r for r in recs if r.name == name] == []
+        return
+    by_id = {r.id: r for r in recs}
+    rec = _one(recs, name)
+    assert by_id[rec.parent].name == want
+    search = _one(recs, "spac.search")
+    if rec is not search:              # no host stage runs inside the search
+        assert rec.end_ns <= search.start_ns or rec.start_ns >= search.end_ns
+
+
 def test_search_notes_generations_and_evaluated_rows(tmp_path):
     s = registry["hft"].override(
         back_annotation=False, top_k=2, trace_params={"duration_s": 8e-5},
